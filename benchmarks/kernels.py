@@ -1,4 +1,4 @@
-"""Substrate kernels: Pallas (interpret mode) vs jnp oracle — allclose + µs."""
+"""Substrate kernels: Pallas (interpreted off the TPU) vs jnp oracle — allclose + µs."""
 
 from __future__ import annotations
 
@@ -34,11 +34,10 @@ def main(rows: List[str]) -> None:
     k = jax.random.normal(ks[1], (b, s, hkv, d), jnp.float32)
     v = jax.random.normal(ks[2], (b, s, hkv, d), jnp.float32)
     ref = reference_attention(q, k, v, causal=True)
-    out = flash_attention_tpu(q, k, v, causal=True, block_q=128, block_k=128,
-                              interpret=True)
+    out = flash_attention_tpu(q, k, v, causal=True, block_q=128, block_k=128)
     err = float(jnp.max(jnp.abs(out - ref)))
     us_pallas = _time(lambda: flash_attention_tpu(
-        q, k, v, causal=True, block_q=128, block_k=128, interpret=True))
+        q, k, v, causal=True, block_q=128, block_k=128))
     us_ref = _time(lambda: flash_attention(q, k, v, causal=True,
                                            q_chunk=128, kv_chunk=128))
     rows.append(f"kernel_flash_prefill,{us_pallas:.0f},"
@@ -53,10 +52,9 @@ def main(rows: List[str]) -> None:
     vc = jax.random.normal(ks[2], (b, s, hkv, d), jnp.float32)
     cl = jnp.asarray(1536, jnp.int32)
     refd = decode_attention(q1, kc, vc, cl)
-    outd = flash_decode_tpu(q1, kc, vc, cl, block_k=512, interpret=True)
+    outd = flash_decode_tpu(q1, kc, vc, cl, block_k=512)
     errd = float(jnp.max(jnp.abs(outd - refd)))
-    us_pallas = _time(lambda: flash_decode_tpu(q1, kc, vc, cl, block_k=512,
-                                               interpret=True))
+    us_pallas = _time(lambda: flash_decode_tpu(q1, kc, vc, cl, block_k=512))
     us_ref = _time(lambda: decode_attention(q1, kc, vc, cl))
     rows.append(f"kernel_flash_decode,{us_pallas:.0f},"
                 f"max_err={errd:.2e};jnp_oracle_us={us_ref:.0f};"

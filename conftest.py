@@ -1,15 +1,8 @@
-"""Root pytest conftest: path bootstrap + offline property-test support.
+"""Root pytest conftest: path bootstrap.
 
-Two jobs, both before any test module is imported:
-
-1.  Make ``repro`` importable even when the caller forgot
-    ``PYTHONPATH=src`` (the tier-1 command sets it; IDEs often don't).
-2.  If the real ``hypothesis`` package is not installed (this container is
-    offline), install the deterministic shim from
-    ``repro.compat.hypothesis_shim`` under the ``hypothesis`` /
-    ``hypothesis.strategies`` module names.  When hypothesis IS installed
-    it is preferred untouched — delete the shim entries from
-    ``sys.modules`` and re-run to compare engines.
+Makes ``repro`` importable even when the caller forgot ``PYTHONPATH=src``
+(the tier-1 command sets it; IDEs often don't), before any test module is
+imported.
 """
 
 import os
@@ -22,11 +15,3 @@ if _SRC not in sys.path:
 _ROOT = os.path.dirname(os.path.abspath(__file__))
 if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
-
-try:
-    import hypothesis  # noqa: F401 — real package wins when present
-except ImportError:
-    from repro.compat import hypothesis_shim as _shim
-
-    sys.modules["hypothesis"] = _shim
-    sys.modules["hypothesis.strategies"] = _shim.strategies

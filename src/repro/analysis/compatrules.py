@@ -1,14 +1,15 @@
-"""compat-boundary: version-gated JAX symbols live only in repro.compat.
+"""compat-boundary: gated JAX symbols live only in repro.compat.
 
-The supported JAX range (ROADMAP, "Supported environment") spans 0.4.37
-through the modern >=0.5 mesh-context API, and the symbols whose presence
-or signature varies across that range may only be touched from
-``src/repro/compat/`` (``meshenv``, ``pallascompat``).  The original
-guard was a token grep; this checker is import/attribute-aware, so it
+The mesh-context and Pallas compiler-params symbols of the installed JAX
+(ROADMAP, "Supported environment") are the API surface most likely to move
+between releases, so they may only be touched from ``src/repro/compat/``
+(``meshenv``, ``pallascompat``).  The original guard was a token grep;
+this checker is import/attribute-aware, so it
 
-* catches ``from jax.sharding import use_mesh``, ``jax.sharding.set_mesh``,
-  aliased module imports, bare uses of a gated name, and the
-  ``axis_types=`` keyword — wherever they appear in real code;
+* catches ``from jax.sharding import get_abstract_mesh``,
+  ``jax.sharding.set_mesh``, aliased module imports, bare uses of a gated
+  name, and the ``axis_types=`` keyword — wherever they appear in real
+  code;
 * does NOT fire on docstrings or comments that merely *mention* a gated
   symbol (the grep's false-positive class, which forced whole-file
   allowlists).
@@ -21,16 +22,14 @@ from typing import Iterable, List
 
 from repro.analysis.framework import Checker, Finding, RepoIndex, register
 
-# symbols whose presence/signature varies across the supported JAX range
+# mesh-context and Pallas compiler-params symbols of the installed JAX
 GATED_SYMBOLS = frozenset({
-    "get_abstract_mesh", "AxisType", "thread_resources",
-    "use_mesh", "set_mesh", "CompilerParams", "TPUCompilerParams",
+    "get_abstract_mesh", "AxisType", "set_mesh", "CompilerParams",
 })
 # call keywords with the same version-gating problem
 GATED_KWARGS = frozenset({"axis_types"})
 
-# the compat package IS the sanctioned home; its tests exercise both API
-# generations by construction
+# the compat package IS the sanctioned home, and its tests exercise it
 ALLOWED_PREFIXES = ("src/repro/compat/",)
 ALLOWED_FILES = ("tests/test_compat.py",)
 

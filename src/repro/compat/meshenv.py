@@ -1,29 +1,25 @@
-"""Mesh-environment abstraction: one API over JAX >=0.5 and JAX 0.4.x.
+"""Mesh-environment layer: the one home of mesh/sharding introspection.
 
-The model stack targets the modern mesh-context API
-(``jax.sharding.get_abstract_mesh`` / ``AxisType`` / ``set_mesh``); older
-installs (0.4.x, as shipped in the offline container) expose none of those
-and instead track the ambient mesh through the ``with mesh:`` thread-local
-(``jax._src.mesh.thread_resources``).  Everything below dispatches on what
-the installed ``jax.sharding`` actually provides — detected per call, so
-tests can monkeypatch either API surface — and returns ``None`` / no-ops
-when no mesh is active, which is the common single-device test path.
+The model stack uses JAX's mesh-context API (``jax.set_mesh`` /
+``jax.sharding.get_abstract_mesh`` / ``AxisType``).  Every query returns
+``None`` / no-ops when no mesh is active, which is the common
+single-device path.
 
 Public surface (the only sanctioned mesh introspection in this repo):
 
-* ``make_mesh(shape, axis_names)``        — version-portable mesh builder
+* ``make_mesh(shape, axis_names)``        — mesh with Auto axis types
 * ``current_mesh()``                      — active mesh or ``None``
 * ``axis_names()`` / ``axis_sizes()``     — ambient-mesh introspection
 * ``mesh_size(mesh, axes)``               — product of named axis extents
-* ``mesh_context(mesh)``                  — portable ``set_mesh``/``with m:``
+* ``mesh_context(mesh)``                  — enter ``mesh`` as the ambient mesh
 * ``with_sharding_constraint(x, spec)``   — ambient-mesh constraint
-* ``shard_map(f, mesh=..., ...)``         — portable shard_map import
+* ``shard_map(f, mesh=..., ...)``         — ``jax.shard_map``
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Dict, Optional, Tuple, Union
+from typing import Dict, Tuple, Union
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -31,75 +27,27 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 Axes = Union[None, str, Tuple[str, ...]]
 
 
-def modern_api() -> bool:
-    """True when the installed jax.sharding exposes the >=0.5 mesh API."""
-    return hasattr(jax.sharding, "get_abstract_mesh")
-
-
-# ---------------------------------------------------------------------------
-# mesh construction
-# ---------------------------------------------------------------------------
-
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
-
-    JAX >=0.5 wants ``axis_types`` spelled out (future default is
-    ``Explicit``); 0.4.x predates the kwarg entirely.
-    """
-    kwargs: Dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names), **kwargs)
-        except TypeError:  # AxisType present but make_mesh predates kwarg
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# ambient-mesh discovery
-# ---------------------------------------------------------------------------
-
-def _legacy_ambient_mesh() -> Optional[Mesh]:
-    """0.4.x: the ``with mesh:`` context lives in mesh_lib.thread_resources."""
-    try:
-        from jax._src import mesh as mesh_lib
-        phys = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:  # noqa: BLE001 — internal layout moved; treat as bare
-        return None
-    return None if phys.empty else phys
+    """``jax.make_mesh`` with explicit Auto axis types (the future default
+    is ``Explicit``, which the model stack's constraints do not use)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=auto,
+                         devices=devices)
 
 
 def current_mesh():
-    """The active mesh — abstract on modern JAX, concrete on 0.4.x — or
-    ``None`` when no mesh context is in effect.
-
-    The modern probe falls back to the legacy thread-local when it comes up
-    empty, so a mesh entered via ``with mesh:`` (the only entry point on
-    builds that expose ``get_abstract_mesh`` but not ``set_mesh``) is still
-    discovered — ``mesh_context`` and ``current_mesh`` agree by
-    construction in every API window.
-    """
-    if modern_api():
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not getattr(m, "empty", False) and m.axis_names:
-            return m
-    return _legacy_ambient_mesh()
+    """The active (abstract) mesh, or ``None`` when no mesh context is in
+    effect."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or getattr(m, "empty", False) or not m.axis_names:
+        return None
+    return m
 
 
 def axis_names() -> Tuple[str, ...]:
     """Axis names of the active mesh (``()`` when unmeshed)."""
     m = current_mesh()
-    if m is None:
-        return ()
-    try:
-        return tuple(m.axis_names)
-    except Exception:  # noqa: BLE001 — half-constructed mock meshes in tests
-        return ()
+    return () if m is None else tuple(m.axis_names)
 
 
 def axis_sizes(mesh=None) -> Dict[str, int]:
@@ -123,54 +71,17 @@ def mesh_size(mesh, axes: Axes) -> int:
     return n
 
 
-# ---------------------------------------------------------------------------
-# mesh context + sharding application
-# ---------------------------------------------------------------------------
-
 @contextlib.contextmanager
 def mesh_context(mesh: Mesh):
-    """Enter ``mesh`` as the ambient mesh, whatever the JAX version.
-
-    Modern JAX: ``jax.sharding.use_mesh`` (always a context manager) is
-    preferred; ``set_mesh`` is tried next but only if its return value
-    actually supports the context-manager protocol (in some versions it is
-    a plain global setter).  Everything else — including 0.4.x, where the
-    Mesh object is itself the context manager — falls back to
-    ``with mesh:``, which ``current_mesh`` can always discover via its
-    legacy thread-local probe.
-    """
-    if modern_api():
-        use = getattr(jax.sharding, "use_mesh", None)
-        if use is not None:
-            with use(mesh):
-                yield mesh
-            return
-        set_m = getattr(jax.sharding, "set_mesh", None)
-        if set_m is not None:
-            ctx = set_m(mesh)
-            if hasattr(ctx, "__enter__"):
-                with ctx:
-                    yield mesh
-                return
-            # plain setter variant: the mesh is now set globally; restore
-            # the previous one (its return value, when it is a mesh) after
-            prev = ctx if ctx is not None else None
-            try:
-                yield mesh
-            finally:
-                set_m(prev)
-            return
-    with mesh:
+    """Enter ``mesh`` as the ambient mesh (``jax.set_mesh``)."""
+    with jax.set_mesh(mesh):
         yield mesh
 
 
 def with_sharding_constraint(x: jax.Array, spec: P) -> jax.Array:
     """Constrain ``x`` to ``spec`` under the ambient mesh (no-op unmeshed).
-
-    On 0.4.x a bare PartitionSpec is only accepted inside the mesh context
-    manager; binding the concrete mesh into a NamedSharding is valid in both
-    worlds, so do that whenever the active mesh is concrete.
-    """
+    A concrete mesh is bound into a NamedSharding; the abstract mesh of a
+    ``mesh_context`` takes the bare PartitionSpec."""
     m = current_mesh()
     if m is None:
         return x
@@ -180,11 +91,6 @@ def with_sharding_constraint(x: jax.Array, spec: P) -> jax.Array:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = False):
-    """Portable shard_map: jax.experimental on <=0.6, jax.shard_map after."""
-    try:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_rep)
-    except ImportError:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep)
+    """``jax.shard_map``; ``check_rep`` maps to its ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)

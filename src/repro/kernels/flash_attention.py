@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat.pallascompat import tpu_compiler_params
+from repro.compat.pallascompat import resolve_interpret, tpu_compiler_params
 from repro.models.attention import NEG_INF
 
 
@@ -77,11 +77,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, window: Optional[int] = None,
                         block_q: int = 256, block_k: int = 512,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Sq, H, D); k/v: (B, Skv, Hkv, D) -> (B, Sq, H, D).
 
-    ``interpret=True`` runs the kernel body on CPU (this container); on real
-    TPU hardware pass interpret=False.
+    ``interpret`` defaults to the backend (``resolve_interpret``).
     """
     b, sq, h, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
@@ -126,7 +125,7 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qr, kr, vr)
     out = out.reshape(b, hkv, rep, sq_p, d).transpose(0, 3, 1, 2, 4) \
         .reshape(b, sq_p, h, d)

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat.pallascompat import tpu_compiler_params
+from repro.compat.pallascompat import resolve_interpret, tpu_compiler_params
 from repro.models.attention import NEG_INF
 
 
@@ -65,7 +65,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
 def flash_decode_tpu(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      cache_len: jax.Array, *, window: Optional[int] = None,
-                     block_k: int = 1024, interpret: bool = True) -> jax.Array:
+                     block_k: int = 1024,
+                     interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, 1, H, D); caches: (B, S, Hkv, D); cache_len: () int32.
 
     Returns (B, 1, H, D).
@@ -111,6 +112,6 @@ def flash_decode_tpu(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b * hkv, rep, d), q.dtype),
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lens, qr, kr, vr)
     return out.reshape(b, hkv, rep, d).reshape(b, 1, h, d)

@@ -33,13 +33,14 @@ bit-for-bit at the model layer.  The jnp oracles are
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat.pallascompat import tpu_compiler_params
+from repro.compat.pallascompat import resolve_interpret, tpu_compiler_params
 from repro.models.attention import NEG_INF, kv_dequantize
 from repro.kernels.tuning import tuning_for
 
@@ -70,9 +71,9 @@ def _paged_kernel(bt_ref, len_ref, q_ref, *refs, page: int, pps: int,
     q = q_ref[0].astype(jnp.float32)                   # (hkv, rep, d)
     for j in range(pps):
         if quant:
-            k = kv_dequantize(k_refs[j][0], ks_refs[j][0][..., None],
+            k = kv_dequantize(k_refs[j][0], ks_refs[j][0],
                               jnp.float32)             # (hkv, page, d)
-            v = kv_dequantize(v_refs[j][0], vs_refs[j][0][..., None],
+            v = kv_dequantize(v_refs[j][0], vs_refs[j][0],
                               jnp.float32)
         else:
             k = k_refs[j][0].astype(jnp.float32)
@@ -106,10 +107,6 @@ def _paged_kernel(bt_ref, len_ref, q_ref, *refs, page: int, pps: int,
 def _kv_index(bb, ip, bt_ref, len_ref, *, pps, j):
     # physical page for (row bb, logical page ip*pps + j), all kv heads
     return (bt_ref[bb, ip * pps + j], 0, 0, 0)
-
-
-def _scale_index(bb, ip, bt_ref, len_ref, *, pps, j):
-    return (bt_ref[bb, ip * pps + j], 0, 0)
 
 
 def _q_index(bb, ip, bt_ref, len_ref):
@@ -159,12 +156,15 @@ def _paged_attention(q, k_pool, v_pool, block_tables, lengths, k_scale,
         + kv_spec + kv_spec
     inputs = [qr] + [kr] * pps + [vr] * pps
     if quant:
-        sc_spec = [pl.BlockSpec((1, hkv, page),
-                                functools.partial(_scale_index, pps=pps, j=j))
+        # scales keep their trailing 1-dim, so the in-body dequant
+        # broadcasts along lanes with no rank-changing reshape (Mosaic
+        # rejects the (hkv, page) -> (hkv, page, 1) shape cast)
+        sc_spec = [pl.BlockSpec((1, hkv, page, 1),
+                                functools.partial(_kv_index, pps=pps, j=j))
                    for j in range(pps)]
         in_specs += sc_spec + sc_spec
-        ksr = k_scale[..., 0].transpose(0, 2, 1)       # (P, Hkv, page)
-        vsr = v_scale[..., 0].transpose(0, 2, 1)
+        ksr = k_scale.transpose(0, 2, 1, 3)            # (P, Hkv, page, 1)
+        vsr = v_scale.transpose(0, 2, 1, 3)
         inputs += [ksr] * pps + [vsr] * pps
 
     out = pl.pallas_call(
@@ -183,7 +183,7 @@ def _paged_attention(q, k_pool, v_pool, block_tables, lengths, k_scale,
         out_shape=jax.ShapeDtypeStruct((b, hkv, kq * rep, d), q.dtype),
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(bt, lens, *inputs)
     return (out.reshape(b, hkv, kq, rep, d).transpose(0, 2, 1, 3, 4)
             .reshape(b, kq, h, d))
@@ -194,7 +194,8 @@ def flash_paged_decode_tpu(q: jax.Array, k_pool: jax.Array,
                            lengths: jax.Array, *,
                            k_scale=None, v_scale=None,
                            pages_per_step=None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None
+                           ) -> jax.Array:
     """q: (B, 1, H, D); pools: (P, page, Hkv, D); block_tables: (B, maxp)
     int32; lengths: (B,) int32 valid tokens per row.  For int8 pools pass
     ``k_scale``/``v_scale``: (P, page, Hkv, 1) per-token-per-head scales.

@@ -24,6 +24,8 @@ The jnp oracles are ``ref.paged_verify_ref`` / ``ref.paged_verify_quant_ref``.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -56,9 +58,9 @@ def _verify_kernel(bt_ref, len_ref, q_ref, *refs, page: int, pps: int,
     q_idx = jax.lax.broadcasted_iota(jnp.int32, (1, q.shape[1], 1), 1) // rep
     for j in range(pps):
         if quant:
-            k = kv_dequantize(k_refs[j][0], ks_refs[j][0][..., None],
+            k = kv_dequantize(k_refs[j][0], ks_refs[j][0],
                               jnp.float32)             # (hkv, page, d)
-            v = kv_dequantize(v_refs[j][0], vs_refs[j][0][..., None],
+            v = kv_dequantize(v_refs[j][0], vs_refs[j][0],
                               jnp.float32)
         else:
             k = k_refs[j][0].astype(jnp.float32)
@@ -89,7 +91,8 @@ def flash_paged_verify_tpu(q: jax.Array, k_pool: jax.Array,
                            lengths: jax.Array, *,
                            k_scale=None, v_scale=None,
                            pages_per_step=None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None
+                           ) -> jax.Array:
     """q: (B, K, H, D) — K new tokens per row, whose KV is already in the
     pool at positions ``lengths[b] .. lengths[b]+K-1``; pools:
     (P, page, Hkv, D); block_tables: (B, maxp) int32; lengths: (B,) int32

@@ -11,17 +11,18 @@ wrappers consult when the caller does not pass ``pages_per_step``
 explicitly.
 
 ``autotune_paged_decode`` is the sweep helper: it times the real kernel
-(interpret mode off-TPU) over candidate values on caller-supplied arrays
-and records the winner.  ``benchmarks/run.py --bench`` runs it at the
-bench's pinned shapes and publishes the chosen tuning in the ``kernel``
-section of ``BENCH_scheduling.json`` so the choice is tracked PR over PR.
+(compiled on a TPU, interpreted elsewhere) over candidate values on
+caller-supplied arrays and records the winner.  ``benchmarks/run.py
+--bench`` runs it at the bench's pinned shapes and publishes the chosen
+tuning in the ``kernel`` section of ``BENCH_scheduling.json`` so the
+choice is tracked PR over PR.
 """
 
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Dict, Iterable, Tuple
+from typing import Dict, Iterable, Optional, Tuple
 
 import jax
 
@@ -61,7 +62,8 @@ def autotune_paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                           block_tables: jax.Array, lengths: jax.Array, *,
                           candidates: Iterable[int] = (1, 2, 4),
                           iters: int = 3,
-                          interpret: bool = True) -> KernelTuning:
+                          interpret: Optional[bool] = None
+                          ) -> KernelTuning:
     """Sweep ``pages_per_step`` candidates on real arrays, record + return
     the fastest.  The winner is keyed by ``(page_size, head_dim, hkv)`` so
     every later kernel call at this shape picks it up automatically.

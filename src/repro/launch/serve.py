@@ -1,30 +1,50 @@
 """End-to-end decentralized serving driver (the paper's system, for real).
 
-Spins up N WWW.Serve nodes, each backed by a REAL JAX engine serving a small
-model; users submit batched requests to hot nodes; the decentralized protocol
-(PoS routing, credit ledger, duels judged by sequence log-likelihood under
-the judges' own models) redistributes them.  The protocol's executor
-assignments are then replayed on real slot-based continuous-batching engines
-behind the ``EngineExecutor`` interface (DESIGN.md §6.1): all engines are
-pumped step-by-step in round-robin, so admissions interleave with decode
-exactly as they would under live traffic, and per-node load is reported from
+Spins up N WWW.Serve nodes, each backed by a REAL JAX engine; users submit
+batched requests to hot nodes; the decentralized protocol (PoS routing,
+credit ledger, duels judged by sequence log-likelihood under the judges'
+own models) redistributes them.  The protocol's executor assignments are
+then replayed on real slot-based continuous-batching engines behind the
+``EngineExecutor`` interface (DESIGN.md §6.1): all engines are pumped
+step-by-step in round-robin, so admissions interleave with decode exactly
+as they would under live traffic, and per-node load is reported from
 ``Executor.load()`` snapshots.
 
+Model size:
+
+* by default the smoke preset of ``--arch`` in float32 (2 layers,
+  d_model 256) — the CPU size;
+* ``--layers N``: the architecture's published widths in bf16, cut to N
+  layers.
+
+Every node serves the same weights (seed 0), one copy per device: a fleet
+of one-chip nodes is replicas of one model, and the sim's quality spread
+lives in the node profiles, not the weights.
+
+Node ``i``'s engine lives on ``jax.devices()[i % len(jax.devices())]``:
+its params, KV pools or slot cache, block tables, lengths and host inputs.
+All engines run in this one process, which holds every local device.
+
     PYTHONPATH=src python -m repro.launch.serve --nodes 4 --requests 24
+    PYTHONPATH=src python -m repro.launch.serve --nodes 2 --requests 8 \\
+        --layers 18 --prompt-len 128 --paged        # on a TPU
 """
 
 from __future__ import annotations
 
 import argparse
 import time
-from typing import Dict, List
+from dataclasses import dataclass
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.core import DuelParams, Network, Node, NodePolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
+from repro.models.config import ModelConfig
 from repro.obs import (Tracer, breakdown_report, set_tracer,
                        write_chrome_trace)
 from repro.serving import (DisaggEngineExecutor, Engine, EngineExecutor,
@@ -32,12 +52,19 @@ from repro.serving import (DisaggEngineExecutor, Engine, EngineExecutor,
 from repro.sim import make_profile
 from repro.sim.workload import Request
 
+MAX_BATCH = 4
 
-def main(argv=None) -> int:
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=4)
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--arch", default="qwen3-8b")
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="serve the published widths of --arch in bf16, "
+                         "cut to N layers, one weight copy per device "
+                         "(default: the float32 smoke preset)")
+    ap.add_argument("--prompt-len", type=int, default=24)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--duel-rate", type=float, default=0.2)
     ap.add_argument("--seed", type=int, default=0)
@@ -71,43 +98,114 @@ def main(argv=None) -> int:
     if args.kv_quant and args.disagg:
         ap.error("--kv-quant is colocated-only: KV handoffs carry fp "
                  "pages (DESIGN.md §6.1-paged)")
+    return args
 
-    cfg = get_config(args.arch).smoke().replace(dtype="float32")
-    if args.kv_quant:
-        cfg = cfg.replace(kv_quant=True)
-    print(f"spinning up {args.nodes} nodes serving {cfg.name}")
+
+def serve_config(arch: str, layers: Optional[int] = None,
+                 kv_quant: bool = False) -> ModelConfig:
+    """The served model: the float32 smoke preset of ``arch``, or with
+    ``layers`` its published widths in bf16 cut to that depth."""
+    if layers is None:
+        cfg = get_config(arch).smoke().replace(dtype="float32")
+    else:
+        cfg = get_config(arch).replace(n_layers=layers, dtype="bfloat16")
+    return cfg.replace(kv_quant=True) if kv_quant else cfg
+
+
+def params_on(cfg: ModelConfig, seed: int, device, made: Dict) -> Dict:
+    """Weights of ``cfg`` drawn from ``seed``, on ``device``.
+
+    The first copy of a seed is drawn on its device under ``jit``, in the
+    config's dtype: nothing is staged on the host or the default device,
+    and no float32 or per-layer copy sits beside the stacked weights.  A
+    seed already drawn on another device is copied device to device.
+    ``made`` memoizes per ``(cfg, seed, device)``, so each device holds one
+    copy of each model's weights however many nodes share them."""
+    key = (cfg, seed, device)
+    if key not in made:
+        src = next((p for (c, s, _), p in made.items()
+                    if (c, s) == (cfg, seed)), None)
+        if src is not None:
+            made[key] = jax.device_put(src, device)
+        else:
+            init = jax.jit(lambda s: registry.init(jax.random.PRNGKey(s),
+                                                    cfg))
+            made[key] = init(jax.device_put(np.int32(seed), device))
+    return made[key]
+
+
+def engines_of(ex: EngineExecutor) -> List[Engine]:
+    """The engine(s) behind one node's executor."""
+    if isinstance(ex, DisaggEngineExecutor):
+        return [ex.prefill, ex.decode]
+    return [ex.engine]
+
+
+@dataclass
+class FleetRun:
+    """What one :func:`serve_fleet` call built and served."""
+
+    args: argparse.Namespace
+    cfg: ModelConfig
+    executors: Dict[str, EngineExecutor]   # node id -> its executor
+    done: Dict[str, List[GenRequest]]      # node id -> requests it completed
+    metrics: object                        # the protocol sim's metrics
+    net: Network
+    wall_s: float                          # sim + real-engine replay
+    spans: list                            # recorded spans (``trace``)
+
+    def completed(self) -> List[GenRequest]:
+        """Every completed request, in submission order."""
+        return sorted((r for rs in self.done.values() for r in rs),
+                      key=lambda r: int(r.rid[1:]))
+
+
+def serve_fleet(args: argparse.Namespace,
+                devices: Optional[Sequence] = None,
+                made: Optional[Dict] = None) -> FleetRun:
+    """Build the fleet ``args`` describes, run the protocol sim, replay its
+    assignments on the real engines, and return what was served.
+
+    ``devices`` defaults to ``jax.devices()``; node ``i`` runs on
+    ``devices[i % len(devices)]``.  ``made`` is the weight memo of
+    :func:`params_on`; passing the same dict to a second call reuses the
+    weights already on a device."""
+    devices = list(jax.devices() if devices is None else devices)
+    made = {} if made is None else made
+    cfg = serve_config(args.arch, args.layers, args.kv_quant)
     rng = np.random.default_rng(args.seed)
-    draft_cfg = draft_params = None
-    if args.spec:
-        # one shared draft model across nodes (a tiny same-tokenizer
-        # sibling; in a real deployment each node brings its own)
-        draft_cfg = cfg.draft()
-        draft_params = registry.init(jax.random.PRNGKey(10_000), draft_cfg)
-
+    draft_cfg = cfg.draft() if args.spec else None
+    # bucket-padded prompts of up to 32 tokens keep the CPU smoke shapes;
+    # longer prompts get a bucket of their own length, so MAX_BATCH of them
+    # fit the default page pool together
+    bucket = max(32, args.prompt_len)
     net = Network(mode="decentralized", seed=args.seed,
                   duel=DuelParams(p_d=args.duel_rate, k_judges=1),
                   init_balance=100.0)
     executors: Dict[str, EngineExecutor] = {}
     for i in range(args.nodes):
         nid = f"node{i+1}"
-        # heterogeneous quality: deeper-trained nodes get lower-temperature
-        # params (stand-in for better models)
-        params = registry.init(jax.random.PRNGKey(i), cfg)
+        dev = devices[i % len(devices)]
+        params = params_on(cfg, 0, dev, made)
+
+        def engine(seed: int, **kw) -> Engine:
+            return Engine(cfg, params, max_batch=MAX_BATCH, bucket=bucket,
+                          seed=seed, **kw)
+
         if args.disagg:
             executors[nid] = DisaggEngineExecutor(
-                Engine(cfg, params, max_batch=4, bucket=32, seed=i,
-                       paged=True),
-                Engine(cfg, params, max_batch=4, bucket=32, seed=1000 + i,
-                       paged=True))
+                engine(i, paged=True), engine(1000 + i, paged=True))
         elif args.spec:
+            # one draft model for the fleet (a tiny same-tokenizer sibling;
+            # in a real deployment each node brings its own), copied to
+            # each device that runs a node
+            draft = params_on(draft_cfg, 10_000, dev, made)
             executors[nid] = SpecEngineExecutor(
-                Engine(cfg, params, max_batch=4, bucket=32, seed=i,
-                       paged=True, spec_draft=(draft_cfg, draft_params),
+                engine(i, paged=True, spec_draft=(draft_cfg, draft),
                        spec_k=args.spec_k))
         else:
             executors[nid] = EngineExecutor(
-                Engine(cfg, params, max_batch=4, bucket=32, seed=i,
-                       paged=args.paged or args.kv_quant))
+                engine(i, paged=args.paged or args.kv_quant))
         executors[nid].owner = nid     # real-engine spans carry the node id
         prof = make_profile("qwen3-8b", "RTX3090", "sglang",
                             quality=0.4 + 0.15 * i)
@@ -122,10 +220,12 @@ def main(argv=None) -> int:
 
     # submit all user requests to node1 (the hot node)
     t_wall = time.time()
-    prompts = [rng.integers(2, cfg.vocab_size, size=24).astype(np.int32)
+    prompts = [rng.integers(2, cfg.vocab_size,
+                            size=args.prompt_len).astype(np.int32)
                for _ in range(args.requests)]
     sim_reqs = [Request(rid=f"r{i}", origin="node1", arrival=0.01 * i,
-                        prompt_tokens=24, output_tokens=args.max_new,
+                        prompt_tokens=args.prompt_len,
+                        output_tokens=args.max_new,
                         slo_s=60.0) for i in range(args.requests)]
     m = net.run(sim_reqs, until=600.0)
 
@@ -136,12 +236,10 @@ def main(argv=None) -> int:
     for c in m.completed:
         if not c.is_duel_extra:
             by_exec.setdefault(c.executor, []).append(int(c.rid[1:]))
-    print(f"protocol assigned: { {k: len(v) for k, v in by_exec.items()} }")
-    done_by_node: Dict[str, List[GenRequest]] = {nid: [] for nid in by_exec}
+    done: Dict[str, List[GenRequest]] = {nid: [] for nid in by_exec}
     for nid, idxs in by_exec.items():
         ex = executors[nid]
-        ex.bind(None, lambda r, st, ft, nid=nid:
-                done_by_node[nid].append(r))
+        ex.bind(None, lambda r, st, ft, nid=nid: done[nid].append(r))
         for i in idxs:
             ex.admit(GenRequest(rid=f"r{i}", tokens=prompts[i],
                                 max_new=args.max_new))
@@ -150,38 +248,55 @@ def main(argv=None) -> int:
         for nid in sorted(busy):
             executors[nid].step()
         busy = {nid for nid in busy if executors[nid].has_work()}
+    wall = time.time() - t_wall
+    spans = set_tracer(old_tracer).spans if args.trace else []
+    return FleetRun(args=args, cfg=cfg, executors=executors, done=done,
+                    metrics=m, net=net, wall_s=wall, spans=spans)
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg = serve_config(args.arch, args.layers, args.kv_quant)
+    print(f"spinning up {args.nodes} nodes serving {cfg.name} "
+          f"({cfg.n_layers} layers, d_model {cfg.d_model}, {cfg.dtype}) "
+          f"over {len(jax.devices())} {jax.devices()[0].platform} device(s)")
+    run = serve_fleet(args)
+    done = run.done
+    print(f"protocol assigned: { {k: len(v) for k, v in done.items()} }")
     total_tokens = 0
-    for nid in sorted(by_exec):
-        ex, done = executors[nid], done_by_node[nid]
+    for nid in sorted(done):
+        ex = run.executors[nid]
         ld, st = ex.load(), ex.engine_stats()
-        total_tokens += sum(len(r.result) for r in done)
-        disagg = (f", {st.handoffs} KV handoffs "
-                  f"({st.handoff_bytes / 1e6:.1f} MB)"
-                  if args.disagg else "")
+        total_tokens += sum(len(r.result) for r in done[nid])
+        extra = (f", {st.handoffs} KV handoffs "
+                 f"({st.handoff_bytes / 1e6:.1f} MB)"
+                 if args.disagg else "")
         if args.spec:
-            disagg = (f", spec accepted {st.spec_accepted}/{st.spec_drafted}"
-                      f" drafts over {st.spec_steps} verifies "
-                      f"(E[tok/step] {ld.expected_tokens_per_step:.2f})")
-        print(f"  {nid}: served {len(done)} requests "
-              f"({st.decode_tokens} decode tokens in "
+            extra = (f", spec accepted {st.spec_accepted}/{st.spec_drafted}"
+                     f" drafts over {st.spec_steps} verifies "
+                     f"(E[tok/step] {ld.expected_tokens_per_step:.2f})")
+        devs = sorted({str(d) for e in engines_of(ex) for d in e.devices()})
+        print(f"  {nid} on {','.join(devs)}: served {len(done[nid])} "
+              f"requests ({st.decode_tokens} decode tokens in "
               f"{st.decode_steps} steps; load: "
               f"{ld.active_streams} active / {ld.queued_streams} queued, "
               f"prefill headroom {ld.prefill_headroom:.2f}, "
-              f"decode headroom {ld.decode_headroom:.2f}{disagg})")
-    dt = time.time() - t_wall
-    print(f"generated {total_tokens} tokens across {len(by_exec)} nodes "
-          f"in {dt:.1f}s wall")
+              f"decode headroom {ld.decode_headroom:.2f}{extra})")
+    m = run.metrics
+    print(f"generated {total_tokens} tokens across {len(done)} nodes "
+          f"in {run.wall_s:.1f}s wall")
     print(f"sim SLO attainment: {m.slo_attainment():.3f}; "
           f"delegation rate: {m.delegation_rate():.2f}; "
           f"avg TTFT: {m.avg_ttft():.2f}s; "
           f"avg queue wait: {m.avg_queue_wait():.2f}s")
+    net = run.net
     print(f"credit balances: "
           f"{ {n: round(net.ledger_balance(n), 1) for n in net.nodes} }")
     if args.trace:
-        tracer = set_tracer(old_tracer)
-        payload = write_chrome_trace(tracer.spans, args.trace)
-        print(breakdown_report(tracer.spans, limit=3))
-        print(f"wrote {len(tracer.spans)} spans "
+        payload = write_chrome_trace(run.spans, args.trace)
+        print(breakdown_report(run.spans, limit=3))
+        print(f"wrote {len(run.spans)} spans "
               f"({len(payload['traceEvents'])} events) to {args.trace}")
     return 0
 

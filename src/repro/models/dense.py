@@ -35,8 +35,10 @@ def init(key: jax.Array, cfg: ModelConfig) -> Dict:
     keys = jax.random.split(key, 16)
 
     def stack(initfn, k, *shape_args, **kw):
-        ks = jax.random.split(k, L)
-        return jnp.stack([initfn(ks[i], *shape_args, **kw) for i in range(L)])
+        # vmapped over per-layer keys: under jit the stacked weight is
+        # generated in place, with no per-layer copies to concatenate
+        return jax.vmap(lambda kk: initfn(kk, *shape_args, **kw))(
+            jax.random.split(k, L))
 
     p: Dict = {
         "embed": cm.embed_init(keys[0], cfg.padded_vocab, d, dt),

@@ -57,6 +57,7 @@ benchmarks use the simulated executor instead (see DESIGN.md §6.1).
 
 from __future__ import annotations
 
+import functools
 import zlib
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -65,6 +66,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.models import registry
 from repro.obs import WALL, get_registry, get_tracer, wall_now
@@ -172,8 +174,24 @@ class _Slot:
         self.out: List[int] = []
 
 
+def _one_device(tree, what: str) -> "jax.Device":
+    """The single device holding every array of ``tree``."""
+    devs = {d for leaf in jax.tree_util.tree_leaves(tree)
+            for d in leaf.devices()}
+    if len(devs) != 1:
+        raise ValueError(f"{what} must sit on exactly one device, found "
+                         f"{sorted(str(d) for d in devs)}")
+    return devs.pop()
+
+
 class Engine:
-    """Persistent-slot continuous batching with a jitted step per bucket."""
+    """Persistent-slot continuous batching with a jitted step per bucket.
+
+    The engine runs on the device that holds ``params``: its KV pools or
+    slot cache, block tables, lengths, carried logits, sampling key and
+    every host input are placed there explicitly, so replicas whose params
+    sit on different devices never share one (``devices()`` reports it).
+    """
 
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 8,
                  bucket: int = 64, seed: int = 0,
@@ -189,7 +207,8 @@ class Engine:
         self.max_batch = max_batch
         self.bucket = bucket
         self.continuous = continuous
-        self.key = jax.random.PRNGKey(seed)
+        self.device = _one_device(params, "engine params")
+        self.key = jax.device_put(jax.random.PRNGKey(seed), self.device)
         self.stats = EngineStats()
         # trace span identity (DESIGN.md §Observability): the owning
         # executor forwards the node id the Node binds onto it
@@ -243,7 +262,12 @@ class Engine:
                 donate_argnums=(1,))
             self._scatter_pages = jax.jit(fam.prefill_to_pages,
                                           donate_argnums=(0,))
-            self._init_pools = fam.init_paged_pools
+            # zero pools are created on the engine's device, never staged
+            # through the default one (they fill most of a chip's HBM)
+            self._init_pools = jax.jit(
+                functools.partial(fam.init_paged_pools, cfg),
+                static_argnums=(0, 1),
+                out_shardings=SingleDeviceSharding(self.device))
             usable = (int(num_pages) if num_pages is not None
                       else max_batch * pages_for(2 * bucket, self.page_size))
             # int8 KV pages: the same HBM budget holds 2x the pages — the
@@ -301,6 +325,9 @@ class Engine:
             if self.spec_k < 1:
                 raise ValueError("spec_k must be >= 1")
             draft_cfg, draft_params = spec_draft
+            if _one_device(draft_params, "draft params") != self.device:
+                raise ValueError("draft params must sit on the engine's "
+                                 "device")
             dfam = registry.get_family(draft_cfg)
             if not (dfam.slot_decode and draft_cfg.sliding_window is None):
                 raise ValueError("draft model must support slot decode "
@@ -353,6 +380,32 @@ class Engine:
                 self._verify = jax.jit(
                     lambda p, c, t: fam.paged_verify(p, cfg, c, t),
                     donate_argnums=(1,))
+
+    def _put(self, x, dtype=None) -> jax.Array:
+        """A host value, committed to this engine's device."""
+        return jax.device_put(np.asarray(x, dtype), self.device)
+
+    def _sample(self, logits: jax.Array, temperature) -> jax.Array:
+        """Next token per row from ``logits`` (B, 1, V), advancing the
+        engine's key.  Eager sampling's own temporaries (the padded-vocab
+        mask) are made on this engine's device too."""
+        self.key, sk = jax.random.split(self.key)
+        with jax.default_device(self.device):
+            return sample(sk, logits, temperature=temperature,
+                          vocab_size=self.cfg.vocab_size)
+
+    def devices(self) -> set:
+        """Every device holding one of this engine's arrays: params, KV
+        pools or slot cache, device block tables and lengths, carried
+        logits, and the draft model and its cache.  By construction this
+        is ``{self.device}``."""
+        arrays = [self.params, self.key, self._cache, self._logits]
+        if self.paged:
+            arrays += [self._pools, self._bt_dev, self._len_dev]
+        if self.spec:
+            arrays += [self.spec_draft_params, self._draft_cache]
+        return {d for leaf in jax.tree_util.tree_leaves(arrays)
+                for d in leaf.devices()}
 
     def _pad_bucket(self, n: int) -> int:
         b = self.bucket
@@ -516,21 +569,21 @@ class Engine:
         with get_tracer().wall("engine.prefill", who=self.owner,
                                rows=n, tokens=plen * n) as sp:
             logits, cache = self._prefill(self.params,
-                                          {"tokens": jnp.asarray(toks)},
-                                          self._capacity, jnp.asarray(last))
+                                          {"tokens": self._put(toks)},
+                                          self._capacity, self._put(last))
             logits.block_until_ready()
         self.stats.prefill_wall_s += sp.dt
         self.stats.prefill_tokens += plen * n
         self.stats.batches += 1
         kv = {k: v for k, v in cache.items() if k != "length"}
-        rows = jnp.asarray([i for i, _ in take])
+        rows = self._put([i for i, _ in take])
         if self._cache is None:
             self._cache = jax.tree_util.tree_map(
                 lambda leaf: jnp.zeros(
                     (leaf.shape[0], self.max_batch) + leaf.shape[2:],
-                    leaf.dtype), kv)
+                    leaf.dtype, device=self.device), kv)
             self._logits = jnp.zeros((self.max_batch, 1, logits.shape[-1]),
-                                     logits.dtype)
+                                     logits.dtype, device=self.device)
         self._cache = jax.tree_util.tree_map(
             lambda p, nw: p.at[:, rows].set(nw), self._cache, kv)
         self._logits = self._logits.at[rows].set(logits)
@@ -733,19 +786,18 @@ class Engine:
         with get_tracer().wall("engine.prefill", who=self.owner, path="cold",
                                rows=n, tokens=plen * n) as sp:
             logits, cache = self._prefill(self.params,
-                                          {"tokens": jnp.asarray(toks)},
-                                          plen, jnp.asarray(last))
+                                          {"tokens": self._put(toks)},
+                                          plen, self._put(last))
             logits.block_until_ready()
         self.stats.prefill_wall_s += sp.dt
         self.stats.prefill_tokens += plen * n
         kv = {k: v for k, v in cache.items() if k != "length"}
         if self._pools is None:
-            self._pools = self._init_pools(self.cfg, self._num_pages,
-                                           self.page_size)
+            self._pools = self._init_pools(self._num_pages, self.page_size)
             self._logits = jnp.zeros((self.max_batch, 1, logits.shape[-1]),
-                                     logits.dtype)
-        self._pools = self._scatter_pages(self._pools, kv, jnp.asarray(phys))
-        rows = jnp.asarray([i for i, _ in cold])
+                                     logits.dtype, device=self.device)
+        self._pools = self._scatter_pages(self._pools, kv, self._put(phys))
+        rows = self._put([i for i, _ in cold])
         self._logits = self._logits.at[rows].set(logits)
 
     def _prefill_warm(self, warm: List[Tuple[int, GenRequest]],
@@ -774,20 +826,20 @@ class Engine:
         self._grow_block_tables(need_w)
         w = self._table_width(lookahead=S)
         cache = {**self._pools,
-                 "block_tables": jnp.asarray(self._block_tables[:, :w]),
-                 "lengths": jnp.asarray(self._lengths, jnp.int32)}
+                 "block_tables": self._put(self._block_tables[:, :w]),
+                 "lengths": self._put(self._lengths, jnp.int32)}
         with get_tracer().wall("engine.prefill", who=self.owner, path="warm",
                                rows=len(warm), tokens=S * len(warm),
                                cached_pages=sum(hits.values())) as sp:
             vlogits, cache = self._verify(self.params, cache,
-                                          jnp.asarray(toks))
+                                          self._put(toks))
             vlogits.block_until_ready()
         self.stats.prefill_wall_s += sp.dt
         self.stats.prefill_tokens += S * len(warm)
         self._pools = {n: cache[n] for n in self._pool_names}
         self._tables_dirty = True
-        rows = jnp.asarray([i for i, _ in warm])
-        pos = jnp.asarray([suf_lens[i] - 1 for i, _ in warm])
+        rows = self._put([i for i, _ in warm])
+        pos = self._put([suf_lens[i] - 1 for i, _ in warm])
         self._logits = self._logits.at[rows].set(vlogits[rows, pos][:, None])
         for i, r in warm:
             self._lengths[i] = len(r.tokens)
@@ -987,8 +1039,8 @@ class Engine:
         with get_tracer().wall("engine.spec_draft", who=self.owner,
                                path="prefill", rows=len(take)) as sp:
             dlogits, dcache = self._draft_prefill(
-                self.spec_draft_params, {"tokens": jnp.asarray(toks)},
-                self._draft_capacity, jnp.asarray(last))
+                self.spec_draft_params, {"tokens": self._put(toks)},
+                self._draft_capacity, self._put(last))
             dlogits.block_until_ready()
         self.stats.draft_wall_s += sp.dt
         dkv = {k: v for k, v in dcache.items() if k != "length"}
@@ -996,8 +1048,8 @@ class Engine:
             self._draft_cache = jax.tree_util.tree_map(
                 lambda leaf: jnp.zeros(
                     (leaf.shape[0], self.max_batch) + leaf.shape[2:],
-                    leaf.dtype), dkv)
-        rows = jnp.asarray([i for i, _ in take])
+                    leaf.dtype, device=self.device), dkv)
+        rows = self._put([i for i, _ in take])
         self._draft_cache = jax.tree_util.tree_map(
             lambda p, nw: p.at[:, rows].set(nw), self._draft_cache, dkv)
         for i, r in take:
@@ -1100,8 +1152,8 @@ class Engine:
             if s is None or not s.out:
                 continue
             cached = int(cached_tokens_fn(s.req)) if cached_tokens_fn else 0
-            pages = jnp.asarray(
-                self._row_pages[i][cached // self.page_size:], jnp.int32)
+            pages = self._put(self._row_pages[i][cached // self.page_size:],
+                              np.int32)
             h = KVHandoff(
                 req=s.req, out=list(s.out), length=int(self._lengths[i]),
                 k=self._pools["k_pool"][:, pages],
@@ -1159,19 +1211,21 @@ class Engine:
             return False               # keep the pin; caller retries
         self._pinned.pop(h.req.rid, None)
         if self._pools is None:
-            self._pools = self._init_pools(self.cfg, self._num_pages,
-                                           self.page_size)
+            self._pools = self._init_pools(self._num_pages, self.page_size)
             self._logits = jnp.zeros(
-                (self.max_batch, 1, h.logits.shape[-1]), h.logits.dtype)
+                (self.max_batch, 1, h.logits.shape[-1]), h.logits.dtype,
+                device=self.device)
         i = free_slots[0]
         fresh = [self._claim_page() for _ in range(fresh_need)]
         if fresh:
-            phys = jnp.asarray(fresh, jnp.int32)
+            phys = self._put(fresh, np.int32)
+            # the handoff's KV comes from the prefill engine, which may
+            # hold another device: the transfer lands it on this one
             self._pools = {
                 "k_pool": self._pools["k_pool"].at[:, phys].set(
-                    h.k[:, :fresh_need]),
+                    jax.device_put(h.k[:, :fresh_need], self.device)),
                 "v_pool": self._pools["v_pool"].at[:, phys].set(
-                    h.v[:, :fresh_need])}
+                    jax.device_put(h.v[:, :fresh_need], self.device))}
         pages = pinned + fresh
         if self.prefix_cache:
             # the transported full prompt pages are now valid content:
@@ -1190,7 +1244,8 @@ class Engine:
         self._lengths[i] = h.length
         self._slot_seq[i] = self._admit_seq
         self._admit_seq += 1
-        self._logits = self._logits.at[i].set(h.logits)
+        self._logits = self._logits.at[i].set(
+            jax.device_put(h.logits, self.device))
         self.stats.handoffs += 1
         self.stats.handoff_bytes += h.kv_bytes
         self.stats.peak_resident = max(self.stats.peak_resident,
@@ -1237,13 +1292,11 @@ class Engine:
         if not resident:
             return []
         # 1. sample next token for all resident rows from their current logits
-        self.key, sk = jax.random.split(self.key)
         temps_np = np.zeros(self.max_batch, np.float32)
         for i in resident:
             temps_np[i] = self._slots[i].req.temperature
-        temps = 0.0 if (temps_np <= 0.0).all() else jnp.asarray(temps_np)
-        cur = sample(sk, self._logits, temperature=temps,
-                     vocab_size=self.cfg.vocab_size)
+        temps = 0.0 if (temps_np <= 0.0).all() else self._put(temps_np)
+        cur = self._sample(self._logits, temps)
         cur_np = np.asarray(cur[:, 0])
         now = wall_now()
         finished: List[GenRequest] = []
@@ -1272,8 +1325,8 @@ class Engine:
                     w = self._table_width()
                     if (self._tables_dirty or self._bt_dev is None
                             or self._bt_dev.shape[1] != w):
-                        self._bt_dev = jnp.asarray(self._block_tables[:, :w])
-                        self._len_dev = jnp.asarray(self._lengths, jnp.int32)
+                        self._bt_dev = self._put(self._block_tables[:, :w])
+                        self._len_dev = self._put(self._lengths, jnp.int32)
                     cache = {**self._pools, "block_tables": self._bt_dev,
                              "lengths": self._len_dev}
                     logits, cache = self._decode_paged(self.params, cache,
@@ -1292,13 +1345,13 @@ class Engine:
                     self._tables_dirty = self.active_slots() != len(survivors)
                 else:
                     cache = {**self._cache,
-                             "length": jnp.asarray(self._lengths, jnp.int32)}
+                             "length": self._put(self._lengths, jnp.int32)}
                     logits, cache = self._decode(self.params, cache, cur)
                     logits.block_until_ready()
                     self._cache = {k: v for k, v in cache.items()
                                    if k != "length"}
             self.stats.decode_wall_s += spn.dt
-            keep = jnp.asarray(survivors)
+            keep = self._put(survivors)
             self._logits = self._logits.at[keep].set(logits[keep])
             self._lengths[survivors] += 1
             self.stats.decode_tokens += len(survivors)
@@ -1329,9 +1382,7 @@ class Engine:
             return []
         # 1. pending token from carried logits (identical to the base step;
         #    spec rows are greedy-only, enforced at submit)
-        self.key, sk = jax.random.split(self.key)
-        cur = sample(sk, self._logits, temperature=0.0,
-                     vocab_size=self.cfg.vocab_size)
+        cur = self._sample(self._logits, 0.0)
         cur_np = np.asarray(cur[:, 0])
         now = wall_now()
         finished: List[GenRequest] = []
@@ -1361,15 +1412,17 @@ class Engine:
                                k=k, batch=len(survivors)) as dsp:
             for j in range(k):
                 dcache = {**self._draft_cache,
-                          "length": jnp.asarray(self._draft_lengths + j,
+                          "length": self._put(self._draft_lengths + j,
                                                 jnp.int32)}
                 dlogits, dcache = self._draft_decode(self.spec_draft_params,
                                                      dcache, tok)
                 dlogits.block_until_ready()
                 self._draft_cache = {n: v for n, v in dcache.items()
                                      if n != "length"}
-                tok = _greedy_tokens(dlogits[:, -1],
-                                     self.spec_draft_cfg.vocab_size)[:, None]
+                with jax.default_device(self.device):
+                    tok = _greedy_tokens(dlogits[:, -1],
+                                         self.spec_draft_cfg.vocab_size
+                                         )[:, None]
                 drafts[:, j] = np.asarray(tok[:, 0])
             # land the last draft's KV too: each proposing forward writes
             # its INPUT token, so d_k would be missing from the draft cache
@@ -1378,7 +1431,7 @@ class Engine:
             # (harmless for rows that accept less: the position is past
             # their valid prefix and overwritten before it is ever attended)
             dcache = {**self._draft_cache,
-                      "length": jnp.asarray(self._draft_lengths + k,
+                      "length": self._put(self._draft_lengths + k,
                                             jnp.int32)}
             dlogits, dcache = self._draft_decode(self.spec_draft_params,
                                                  dcache, tok)
@@ -1397,19 +1450,20 @@ class Engine:
         # still trimmed to the pages the k+1 writes can touch
         w = self._table_width(lookahead=self.spec_k + 1)
         cache = {**self._pools,
-                 "block_tables": jnp.asarray(self._block_tables[:, :w]),
-                 "lengths": jnp.asarray(self._lengths, jnp.int32)}
+                 "block_tables": self._put(self._block_tables[:, :w]),
+                 "lengths": self._put(self._lengths, jnp.int32)}
         with get_tracer().wall("engine.spec_verify", who=self.owner,
                                k=k, batch=len(survivors)) as vsp:
             vlogits, cache = self._verify(self.params, cache,
-                                          jnp.asarray(toks))
+                                          self._put(toks))
             vlogits.block_until_ready()
         self.stats.decode_wall_s += vsp.dt
         self.stats.verify_wall_s += vsp.dt
         self._pools = {n: cache[n] for n in self._pool_names}
         # the target's greedy choice at every position, with the same
         # vocab masking + argmax as sample(temperature=0)
-        tgt = np.asarray(_greedy_tokens(vlogits, self.cfg.vocab_size))
+        with jax.default_device(self.device):
+            tgt = np.asarray(_greedy_tokens(vlogits, self.cfg.vocab_size))
         # 5. per row: accept the longest draft prefix matching the target,
         #    emit it under the usual EOS/budget rules, advance the caches
         #    over pending + accepted tokens only
@@ -1452,8 +1506,8 @@ class Engine:
         #    target's distribution after [pending, d_1..d_a] — next step's
         #    argmax emits the correction (or the bonus token when a == k)
         if rows:
-            ridx = jnp.asarray(rows)
-            upd = vlogits[ridx, jnp.asarray(pos)][:, None]
+            ridx = self._put(rows)
+            upd = vlogits[ridx, self._put(pos)][:, None]
             self._logits = self._logits.at[ridx].set(upd)
         self.stats.decode_steps += 1
         self.stats.spec_steps += 1
@@ -1483,7 +1537,7 @@ class Engine:
         toks = np.full((len(reqs), plen), self.eos_id, np.int32)
         for i, r in enumerate(reqs):
             toks[i, plen - len(r.tokens):] = r.tokens     # left-pad
-        batch = {"tokens": jnp.asarray(toks)}
+        batch = {"tokens": self._put(toks)}
         cap = plen + self._pad_bucket(max_new)
         with get_tracer().wall("engine.prefill", who=self.owner, path="wave",
                                rows=len(reqs),
@@ -1502,12 +1556,10 @@ class Engine:
         temps_np = np.array([r.temperature for r in reqs], np.float32)
         # all-greedy batches (the default) keep the scalar fast path in
         # sample(), skipping the per-step Gumbel draw over the vocab
-        temps = 0.0 if (temps_np <= 0.0).all() else jnp.asarray(temps_np)
+        temps = 0.0 if (temps_np <= 0.0).all() else self._put(temps_np)
         budgets = np.array([r.max_new for r in reqs])
         for step in range(max_new):
-            self.key, sk = jax.random.split(self.key)
-            cur = sample(sk, logits, temperature=temps,
-                         vocab_size=self.cfg.vocab_size)
+            cur = self._sample(logits, temps)
             out[:, step] = np.asarray(cur[:, 0])
             if step == 0:
                 now = wall_now()
@@ -1537,7 +1589,7 @@ class Engine:
     def logprob_of(self, tokens: np.ndarray) -> float:
         """Sequence log-likelihood under this engine's model — used by the
         real-engine duel judges (DESIGN.md §6.2)."""
-        t = jnp.asarray(tokens[None, :])
+        t = self._put(tokens[None, :])
         logits = registry.apply_logits(self.params, self.cfg,
                                        {"tokens": t[:, :-1]},
                                        q_chunk=256, kv_chunk=256)

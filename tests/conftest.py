@@ -1,10 +1,9 @@
 """Collection guard: fail fast, with an actionable message, before pytest
 prints 10 modules' worth of identical ImportError tracebacks.
 
-The root ``conftest.py`` bootstraps ``sys.path`` and the hypothesis shim;
-this file verifies the environment actually works (repro importable, jax
-present, property-test API available) and aborts collection with one clear
-diagnostic when it doesn't.
+The root ``conftest.py`` bootstraps ``sys.path``; this file verifies the
+environment actually works (repro importable, jax and hypothesis present)
+and aborts collection with one clear diagnostic when it doesn't.
 
 It also turns on strict JAX numerics for the whole suite: implicit rank
 promotion (``(4,) + (2, 4)``-style broadcasts) is the classic source of
@@ -31,9 +30,7 @@ def _guard() -> None:
     try:
         from hypothesis import given, settings, strategies  # noqa: F401
     except ImportError as e:
-        problems.append(
-            f"hypothesis API unavailable ({e}); the root conftest.py "
-            f"should have installed repro.compat.hypothesis_shim")
+        problems.append(f"hypothesis is required for the test suite: {e}")
     if problems:
         raise pytest.UsageError(
             "test environment broken:\n  - " + "\n  - ".join(problems))

@@ -74,10 +74,10 @@ SEEDED = {
     "compat-boundary": {
         **MD_STUBS,
         "src/repro/serving/x.py": """\
-            from jax.sharding import use_mesh
+            from jax.sharding import set_mesh
 
             def f(m):
-                with use_mesh(m):
+                with set_mesh(m):
                     return 1
         """,
     },
@@ -151,9 +151,9 @@ class TestCompatBoundary:
 
     def test_compat_package_and_docstrings_are_silent(self, tmp_path):
         root = mk_repo(tmp_path, {**MD_STUBS, "src/repro/compat/x.py": """\
-            from jax.sharding import use_mesh, set_mesh
+            from jax.sharding import get_abstract_mesh, set_mesh
         """, "src/repro/serving/y.py": '''\
-            """Mentions use_mesh and AxisType only in prose."""
+            """Mentions get_abstract_mesh and AxisType only in prose."""
             # a comment about set_mesh is fine too
             X = 1
         '''})
@@ -559,7 +559,7 @@ class TestDocAnchors:
 class TestSuppression:
     def test_inline_and_comment_above_suppress(self, tmp_path):
         root = mk_repo(tmp_path, {**MD_STUBS, "src/repro/serving/x.py": """\
-            from jax.sharding import use_mesh  # repro: allow[compat-boundary]
+            from jax.sharding import get_abstract_mesh  # repro: allow[compat-boundary]
 
             # justified exception:  # repro: allow[compat-boundary]
             from jax.sharding import set_mesh
@@ -570,7 +570,7 @@ class TestSuppression:
 
     def test_suppression_is_rule_scoped(self, tmp_path):
         root = mk_repo(tmp_path, {**MD_STUBS, "src/repro/serving/x.py": """\
-            from jax.sharding import use_mesh  # repro: allow[layering]
+            from jax.sharding import get_abstract_mesh  # repro: allow[layering]
         """})
         report = analyze(root, "compat-boundary")
         assert rule_ids(report) == ["compat-boundary"]

@@ -1,4 +1,5 @@
-"""Compat layer: version-gate hygiene, both mesh backends, hypothesis shim.
+"""Compat layer: gated-symbol hygiene, the mesh-environment layer, and
+Pallas interpret mode.
 
 Three families of tests:
 
@@ -9,14 +10,13 @@ Three families of tests:
     — the historical test names stay so the contract's history stays
     greppable, while the string greps they once were (with their
     docstring false positives and whole-file allowlists) are gone.
-2.  Unit tests for ``repro.compat.meshenv`` exercising BOTH the modern
-    (>=0.5, simulated via monkeypatching) and legacy (0.4.x) code paths,
-    whichever JAX is actually installed.
-3.  Determinism/correctness tests for the vendored hypothesis shim.
+2.  Unit tests for ``repro.compat.meshenv``: on the installed JAX's real
+    mesh context, and against a monkeypatched abstract mesh.
+3.  ``repro.compat.pallascompat.resolve_interpret``: the interpreter runs
+    only off the TPU.
 """
 
 import pathlib
-import random
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,6 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis import run_analysis
-from repro.compat import hypothesis_shim as shim
 from repro.compat import meshenv
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
@@ -296,22 +295,19 @@ class TestBenchSchema:
 
 
 # ---------------------------------------------------------------------------
-# 2. meshenv — legacy (0.4.x) path
+# 2. meshenv — the installed JAX's mesh context
 # ---------------------------------------------------------------------------
 
-def _force_legacy(monkeypatch):
-    monkeypatch.delattr(jax.sharding, "get_abstract_mesh", raising=False)
-
-
 class TestMeshEnvLegacy:
-    def test_no_mesh_is_none(self, monkeypatch):
-        _force_legacy(monkeypatch)
+    """The mesh-environment contract on the installed JAX's real
+    ``jax.set_mesh`` context."""
+
+    def test_no_mesh_is_none(self):
         assert meshenv.current_mesh() is None
         assert meshenv.axis_names() == ()
         assert meshenv.axis_sizes() == {}
 
-    def test_mesh_context_sets_ambient_mesh(self, monkeypatch):
-        _force_legacy(monkeypatch)
+    def test_mesh_context_sets_ambient_mesh(self):
         m = meshenv.make_mesh((1, 1), ("data", "model"))
         with meshenv.mesh_context(m):
             got = meshenv.current_mesh()
@@ -320,8 +316,7 @@ class TestMeshEnvLegacy:
             assert meshenv.axis_sizes() == {"data": 1, "model": 1}
         assert meshenv.current_mesh() is None
 
-    def test_with_sharding_constraint_under_jit(self, monkeypatch):
-        _force_legacy(monkeypatch)
+    def test_with_sharding_constraint_under_jit(self):
         m = meshenv.make_mesh((1, 1), ("data", "model"))
         x = jnp.arange(16.0).reshape(4, 4)
         with meshenv.mesh_context(m):
@@ -329,13 +324,11 @@ class TestMeshEnvLegacy:
                 a, P("data", None)))(x)
         np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
 
-    def test_constraint_is_noop_without_mesh(self, monkeypatch):
-        _force_legacy(monkeypatch)
+    def test_constraint_is_noop_without_mesh(self):
         x = jnp.ones((2, 2))
         assert meshenv.with_sharding_constraint(x, P(None, None)) is x
 
-    def test_shard_map_runs(self, monkeypatch):
-        _force_legacy(monkeypatch)
+    def test_shard_map_runs(self):
         m = meshenv.make_mesh((1, 1), ("data", "model"))
         f = meshenv.shard_map(lambda a: a * 2, mesh=m,
                               in_specs=P(None, None),
@@ -343,9 +336,8 @@ class TestMeshEnvLegacy:
         np.testing.assert_array_equal(np.asarray(f(jnp.ones((2, 2)))),
                                       2 * np.ones((2, 2)))
 
-    def test_logical_spec_resolution(self, monkeypatch):
+    def test_logical_spec_resolution(self):
         """models.common routes through meshenv: batch -> present axes."""
-        _force_legacy(monkeypatch)
         from repro.models import common as cm
         m = meshenv.make_mesh((1, 1), ("data", "model"))
         with meshenv.mesh_context(m):
@@ -356,7 +348,7 @@ class TestMeshEnvLegacy:
 
 
 # ---------------------------------------------------------------------------
-# 2b. meshenv — modern (>=0.5) path, simulated
+# 2b. meshenv — against a simulated abstract mesh
 # ---------------------------------------------------------------------------
 
 class _FakeAbstractMesh:
@@ -387,7 +379,6 @@ class TestMeshEnvModern:
     def test_modern_detection_and_current_mesh(self, monkeypatch):
         fake = _FakeAbstractMesh({"data": 2, "model": 4})
         self._install(monkeypatch, fake)
-        assert meshenv.modern_api()
         assert meshenv.current_mesh() is fake
         assert meshenv.axis_names() == ("data", "model")
         assert meshenv.axis_sizes() == {"data": 2, "model": 4}
@@ -398,58 +389,6 @@ class TestMeshEnvModern:
         self._install(monkeypatch, _FakeAbstractMesh({}, empty=True))
         assert meshenv.current_mesh() is None
         assert meshenv.axis_names() == ()
-
-    def test_mesh_context_prefers_use_mesh(self, monkeypatch):
-        """use_mesh is always a context manager; it must win over set_mesh
-        even when both exist (set_mesh is a plain setter in some versions)."""
-        self._install(monkeypatch, _FakeAbstractMesh({}, empty=True))
-        events = []
-
-        @__import__("contextlib").contextmanager
-        def fake_use_mesh(m):
-            events.append(("enter", m))
-            yield
-            events.append(("exit", m))
-
-        monkeypatch.setattr(jax.sharding, "use_mesh", fake_use_mesh,
-                            raising=False)
-        monkeypatch.setattr(
-            jax.sharding, "set_mesh",
-            lambda m: events.append(("set", m)), raising=False)
-        with meshenv.mesh_context("M"):
-            pass
-        assert events == [("enter", "M"), ("exit", "M")]
-
-    def test_mesh_context_set_mesh_plain_setter(self, monkeypatch):
-        """set_mesh variants that just set a global (returning the previous
-        mesh, not a context manager) must still enter/restore correctly."""
-        self._install(monkeypatch, _FakeAbstractMesh({}, empty=True))
-        monkeypatch.delattr(jax.sharding, "use_mesh", raising=False)
-        state = {"mesh": "OLD"}
-
-        def fake_set_mesh(m):
-            prev, state["mesh"] = state["mesh"], m
-            return prev
-
-        monkeypatch.setattr(jax.sharding, "set_mesh", fake_set_mesh,
-                            raising=False)
-        with meshenv.mesh_context("NEW"):
-            assert state["mesh"] == "NEW"
-        assert state["mesh"] == "OLD"
-
-    def test_legacy_entry_found_despite_modern_probe(self, monkeypatch):
-        """API window with get_abstract_mesh but no set_mesh/use_mesh:
-        mesh_context enters via ``with mesh:`` and current_mesh must still
-        discover it (legacy fallback behind the empty modern probe)."""
-        self._install(monkeypatch, _FakeAbstractMesh({}, empty=True))
-        monkeypatch.delattr(jax.sharding, "set_mesh", raising=False)
-        monkeypatch.delattr(jax.sharding, "use_mesh", raising=False)
-        m = meshenv.make_mesh((1, 1), ("data", "model"))
-        with meshenv.mesh_context(m):
-            got = meshenv.current_mesh()
-            assert got is not None
-            assert tuple(got.axis_names) == ("data", "model")
-        assert meshenv.current_mesh() is None
 
     def test_make_mesh_passes_axis_types(self, monkeypatch):
         seen = {}
@@ -463,23 +402,6 @@ class TestMeshEnvModern:
                             raising=False)
         assert meshenv.make_mesh((2, 2), ("data", "model")) == "mesh"
         assert seen["axis_types"] == ("auto", "auto")
-
-    def test_make_mesh_retries_without_axis_types(self, monkeypatch):
-        """AxisType present but make_mesh predating the kwarg (or the
-        legacy API entirely): the builder must fall back cleanly."""
-        calls = []
-
-        def fake_make_mesh(shapes, names, **kw):
-            calls.append(kw)
-            if "axis_types" in kw:
-                raise TypeError("unexpected keyword argument 'axis_types'")
-            return "legacy-mesh"
-
-        monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
-        monkeypatch.setattr(jax.sharding, "AxisType", _FakeAxisType,
-                            raising=False)
-        assert meshenv.make_mesh((1, 1), ("data", "model")) == "legacy-mesh"
-        assert len(calls) == 2 and "axis_types" not in calls[1]
 
     def test_modern_constraint_uses_bare_spec(self, monkeypatch):
         """With an (abstract, non-concrete) mesh active, the constraint is
@@ -499,75 +421,24 @@ class TestMeshEnvModern:
         assert not isinstance(captured["sharding"], Mesh)
 
 
+
 # ---------------------------------------------------------------------------
-# 3. hypothesis shim
+# 3. pallascompat — interpret mode follows the backend
 # ---------------------------------------------------------------------------
 
-class TestHypothesisShim:
-    def test_draws_are_deterministic_across_runs(self):
-        runs = []
-        for _ in range(2):
-            seen = []
+class TestResolveInterpret:
+    def test_follows_the_backend(self, monkeypatch):
+        from repro.compat import pallascompat as pc
+        assert pc.resolve_interpret() is (jax.default_backend() != "tpu")
+        monkeypatch.setattr(pc, "on_tpu", lambda: True)
+        assert pc.resolve_interpret() is False
+        # an explicit False compiles for the chip from any process
+        assert pc.resolve_interpret(False) is False
 
-            @shim.given(shim.strategies.integers(0, 1000),
-                        f=shim.strategies.floats(0.0, 1.0))
-            @shim.settings(max_examples=10, deadline=None)
-            def prop(n, f):
-                seen.append((n, f))
-
-            prop()
-            runs.append(list(seen))
-        assert runs[0] == runs[1]
-        assert len(runs[0]) == 10
-
-    def test_strategy_bounds(self):
-        rng = random.Random(0)
-        st = shim.strategies
-        for _ in range(200):
-            assert 3 <= st.integers(3, 7).draw(rng) <= 7
-            assert 0.25 <= st.floats(0.25, 0.75).draw(rng) <= 0.75
-            assert st.sampled_from(["a", "b"]).draw(rng) in ("a", "b")
-            lst = st.lists(st.integers(0, 1), min_size=2,
-                           max_size=5).draw(rng)
-            assert 2 <= len(lst) <= 5
-            assert isinstance(st.booleans().draw(rng), bool)
-
-    def test_composite_and_settings(self):
-        st = shim.strategies
-        calls = []
-
-        @st.composite
-        def pairs(draw):
-            a = draw(st.integers(0, 10))
-            return (a, draw(st.sampled_from([a, -a])))
-
-        @shim.given(pairs())
-        @shim.settings(max_examples=7, deadline=None)
-        def prop(p):
-            calls.append(p)
-            assert abs(p[1]) == p[0]
-
-        prop()
-        assert len(calls) == 7
-
-    def test_failure_reports_falsifying_example(self):
-        @shim.given(shim.strategies.integers(5, 9))
-        @shim.settings(max_examples=3, deadline=None)
-        def prop(n):
-            assert n < 5
-
-        with pytest.raises(AssertionError, match="falsifying example"):
-            prop()
-
-    def test_methods_are_supported(self):
-        """@given on a method must thread ``self`` through untouched."""
-        outer = self
-
-        class Holder:
-            @shim.given(shim.strategies.integers(1, 3))
-            @shim.settings(max_examples=4, deadline=None)
-            def check(self, n):
-                assert outer is not None
-                assert 1 <= n <= 3
-
-        Holder().check()
+    def test_interpreter_refused_on_tpu(self, monkeypatch):
+        from repro.compat import pallascompat as pc
+        monkeypatch.setattr(pc, "on_tpu", lambda: False)
+        assert pc.resolve_interpret(True) is True
+        monkeypatch.setattr(pc, "on_tpu", lambda: True)
+        with pytest.raises(ValueError, match="interpret"):
+            pc.resolve_interpret(True)

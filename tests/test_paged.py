@@ -83,8 +83,8 @@ _MODEL_CACHE = {}
 
 
 def _smoke_model():
-    """Memoized smoke model — also reachable from @given property tests,
-    whose wrappers the hypothesis shim makes opaque to fixture injection."""
+    """Memoized smoke model, shared by the ``setup`` fixture and the
+    @given property tests that call it directly."""
     if "cp" not in _MODEL_CACHE:
         import jax
         from repro.configs import get_config
