@@ -24,6 +24,13 @@ Two recording styles:
   ``obs-lint/wall-clock`` rule (DESIGN.md §7) keeps raw
   ``time.perf_counter()`` calls from creeping back in.
 
+An enabled tracer built with an ``annotate`` factory also opens a profiler
+annotation of the block's name for the block's extent
+(``Tracer(annotate=jax.profiler.TraceAnnotation)``), so the wall spans
+appear on the profiler's host timeline beside the device's operations.
+The factory comes from the caller: this module imports nothing but the
+standard library.
+
 ``Span`` itself is constructed only inside ``repro.obs``
 (``obs-lint/span-construction``, same pattern as the gossip
 digest-construction rule): everything else goes through the ``Tracer``
@@ -35,7 +42,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List
+from typing import Any, Callable, Dict, List, Optional
 
 # clock domains
 SIM = "sim"      # EventLoop.now — simulated seconds (core/sim layers)
@@ -72,13 +79,18 @@ class Tracer:
     so instrumented code pays one truthiness check per would-be span.
     Drivers that want a trace either ``set_tracer(Tracer())`` for the
     scope of a run or pass an explicit tracer to the objects they build.
+    ``annotate``, when given, is called with a wall block's name while the
+    tracer is enabled and returns a context manager held open for the
+    block (a profiler annotation); sim-clock spans never call it.
     """
 
-    __slots__ = ("enabled", "spans")
+    __slots__ = ("enabled", "spans", "annotate")
 
-    def __init__(self, enabled: bool = True) -> None:
+    def __init__(self, enabled: bool = True,
+                 annotate: Optional[Callable[[str], Any]] = None) -> None:
         self.enabled = enabled
         self.spans: List[Span] = []
+        self.annotate = annotate
 
     # ------------------------------------------------------------ recording
     def span(self, name: str, rid: str, who: str, t0: float, t1: float,
@@ -121,11 +133,13 @@ class WallSpan:
     The measurement is unconditional because the serving layer's
     ``EngineStats`` accumulators (``decode_wall_s`` etc.) are fed from
     ``dt`` and must keep working with tracing off; only the span append
-    is gated on the tracer.  Hand-rolled (no ``contextlib``) to keep the
-    per-decode-step overhead to two clock reads and one allocation.
+    is gated on the tracer, and so is the tracer's profiler annotation.
+    Hand-rolled (no ``contextlib``) to keep the per-block overhead to two
+    clock reads and one allocation.
     """
 
-    __slots__ = ("_tracer", "_name", "_rid", "_who", "_attrs", "t0", "t1")
+    __slots__ = ("_tracer", "_name", "_rid", "_who", "_attrs", "_ann",
+                 "t0", "t1")
 
     def __init__(self, tracer: Tracer, name: str, rid: str, who: str,
                  attrs: Dict[str, Any]) -> None:
@@ -134,10 +148,15 @@ class WallSpan:
         self._rid = rid
         self._who = who
         self._attrs = attrs
+        self._ann = None
         self.t0 = 0.0
         self.t1 = 0.0
 
     def __enter__(self) -> "WallSpan":
+        t = self._tracer
+        if t.enabled and t.annotate is not None:
+            self._ann = t.annotate(self._name)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -147,7 +166,13 @@ class WallSpan:
         if t.enabled:
             t.spans.append(Span(self._name, self._rid, self._who,
                                 self.t0, self.t1, WALL, self._attrs))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
+
+    def note(self, **attrs: Any) -> None:
+        """Add attributes known only inside the block (a table width)."""
+        self._attrs.update(attrs)
 
     @property
     def dt(self) -> float:

@@ -114,6 +114,11 @@ class EngineStats:
     decode_steps: int = 0         # batched decode_step invocations
     prefill_wall_s: float = 0.0   # wall time inside prefill calls
     decode_wall_s: float = 0.0    # wall time inside decode_step calls
+    # real prompt tokens the prefill forwards computed for (a warm prefill:
+    # the uncached suffix); prefill_tokens counts the padded positions
+    prefill_prompt_tokens: int = 0
+    steps: int = 0                # slot/paged step() calls (engine.step)
+    step_wall_s: float = 0.0      # wall time inside them
     peak_resident: int = 0        # max concurrently resident sequences
     preempted: int = 0            # paged: preempt-and-requeue events
     handoffs: int = 0             # disagg: KV handoffs extracted/accepted
@@ -219,20 +224,23 @@ class Engine:
         # trailing pads would evict real in-window KV — window configs stay
         # on the left-padded lock-step wave path
         self.slot_decode = fam.slot_decode and cfg.sliding_window is None
+        # the jitted steps are named functions, so a profile's module line
+        # reads ``jit_paged_decode_step`` rather than ``jit__lambda``
         if self.slot_decode:
-            self._prefill = jax.jit(
-                lambda p, b, cap, lp: fam.prefill(p, cfg, b, q_chunk=256,
-                                                  kv_chunk=256, capacity=cap,
-                                                  last_positions=lp),
-                static_argnums=(2,))
+            def prefill_step(p, b, cap, lp):
+                return fam.prefill(p, cfg, b, q_chunk=256, kv_chunk=256,
+                                   capacity=cap, last_positions=lp)
         else:
             # families without per-row cache depths fall back to left-padded
             # lock-step wave batching
-            self._prefill = jax.jit(
-                lambda p, b, cap: fam.prefill(p, cfg, b, q_chunk=256,
-                                              kv_chunk=256, capacity=cap),
-                static_argnums=(2,))
-        self._decode = jax.jit(lambda p, c, t: fam.decode_step(p, cfg, c, t))
+            def prefill_step(p, b, cap):
+                return fam.prefill(p, cfg, b, q_chunk=256, kv_chunk=256,
+                                   capacity=cap)
+        self._prefill = jax.jit(prefill_step, static_argnums=(2,))
+
+        def decode_step(p, c, t):
+            return fam.decode_step(p, cfg, c, t)
+        self._decode = jax.jit(decode_step)
         self.eos_id = cfg.eos_id
 
         # persistent slot state
@@ -257,9 +265,10 @@ class Engine:
             # is independent of pool size (§Perf-kernels).  Never reuse a
             # cache array after passing it in — the engine always reads the
             # returned cache.
-            self._decode_paged = jax.jit(
-                lambda p, c, t: fam.paged_decode(p, cfg, c, t),
-                donate_argnums=(1,))
+            def paged_decode_step(p, c, t):
+                return fam.paged_decode(p, cfg, c, t)
+            self._decode_paged = jax.jit(paged_decode_step,
+                                         donate_argnums=(1,))
             self._scatter_pages = jax.jit(fam.prefill_to_pages,
                                           donate_argnums=(0,))
             # zero pools are created on the engine's device, never staged
@@ -338,17 +347,18 @@ class Engine:
                                  "(vocab_size / eos_id)")
             self.spec_draft_cfg = draft_cfg
             self.spec_draft_params = draft_params
-            self._verify = jax.jit(
-                lambda p, c, t: fam.paged_verify(p, cfg, c, t),
-                donate_argnums=(1,))
-            self._draft_prefill = jax.jit(
-                lambda p, b, cap, lp: dfam.prefill(p, draft_cfg, b,
-                                                   q_chunk=256, kv_chunk=256,
-                                                   capacity=cap,
-                                                   last_positions=lp),
-                static_argnums=(2,))
-            self._draft_decode = jax.jit(
-                lambda p, c, t: dfam.decode_step(p, draft_cfg, c, t))
+            self._verify = self._verify_jit(fam)
+
+            def draft_prefill_step(p, b, cap, lp):
+                return dfam.prefill(p, draft_cfg, b, q_chunk=256,
+                                    kv_chunk=256, capacity=cap,
+                                    last_positions=lp)
+
+            def draft_decode_step(p, c, t):
+                return dfam.decode_step(p, draft_cfg, c, t)
+            self._draft_prefill = jax.jit(draft_prefill_step,
+                                          static_argnums=(2,))
+            self._draft_decode = jax.jit(draft_decode_step)
             # draft slot cache: contiguous per-row-depth KV, mirrored to the
             # target's slots (re-prefilled from scratch after preemption)
             self._draft_cache: Optional[Dict] = None
@@ -377,9 +387,16 @@ class Engine:
                 # the uncached suffix is computed, attending to the shared
                 # prefix pages through the block-table indirection (the
                 # spec engine already built this jit above)
-                self._verify = jax.jit(
-                    lambda p, c, t: fam.paged_verify(p, cfg, c, t),
-                    donate_argnums=(1,))
+                self._verify = self._verify_jit(fam)
+
+    def _verify_jit(self, fam) -> Callable:
+        """The multi-token paged verify forward (speculative verify and
+        warm prefill), its cache donated like the paged decode's."""
+        cfg = self.cfg
+
+        def paged_verify_step(p, c, t):
+            return fam.paged_verify(p, cfg, c, t)
+        return jax.jit(paged_verify_step, donate_argnums=(1,))
 
     def _put(self, x, dtype=None) -> jax.Array:
         """A host value, committed to this engine's device."""
@@ -566,14 +583,17 @@ class Engine:
         for j, (_, r) in enumerate(take):
             toks[j, : len(r.tokens)] = r.tokens      # right-pad (inert)
             last[j] = len(r.tokens) - 1
+        real = sum(len(r.tokens) for _, r in take)
         with get_tracer().wall("engine.prefill", who=self.owner,
-                               rows=n, tokens=plen * n) as sp:
+                               rows=n, tokens=plen * n,
+                               prompt_tokens=real) as sp:
             logits, cache = self._prefill(self.params,
                                           {"tokens": self._put(toks)},
                                           self._capacity, self._put(last))
             logits.block_until_ready()
         self.stats.prefill_wall_s += sp.dt
         self.stats.prefill_tokens += plen * n
+        self.stats.prefill_prompt_tokens += real
         self.stats.batches += 1
         kv = {k: v for k, v in cache.items() if k != "length"}
         rows = self._put([i for i, _ in take])
@@ -783,14 +803,17 @@ class Engine:
             toks[j, : len(r.tokens)] = r.tokens      # right-pad (inert)
             last[j] = len(r.tokens) - 1
             phys[j, : len(self._row_pages[i])] = self._row_pages[i]
+        real = sum(len(r.tokens) for _, r in cold)
         with get_tracer().wall("engine.prefill", who=self.owner, path="cold",
-                               rows=n, tokens=plen * n) as sp:
+                               rows=n, tokens=plen * n,
+                               prompt_tokens=real) as sp:
             logits, cache = self._prefill(self.params,
                                           {"tokens": self._put(toks)},
                                           plen, self._put(last))
             logits.block_until_ready()
         self.stats.prefill_wall_s += sp.dt
         self.stats.prefill_tokens += plen * n
+        self.stats.prefill_prompt_tokens += real
         kv = {k: v for k, v in cache.items() if k != "length"}
         if self._pools is None:
             self._pools = self._init_pools(self._num_pages, self.page_size)
@@ -828,14 +851,17 @@ class Engine:
         cache = {**self._pools,
                  "block_tables": self._put(self._block_tables[:, :w]),
                  "lengths": self._put(self._lengths, jnp.int32)}
+        real = sum(suf_lens.values())
         with get_tracer().wall("engine.prefill", who=self.owner, path="warm",
                                rows=len(warm), tokens=S * len(warm),
+                               prompt_tokens=real,
                                cached_pages=sum(hits.values())) as sp:
             vlogits, cache = self._verify(self.params, cache,
                                           self._put(toks))
             vlogits.block_until_ready()
         self.stats.prefill_wall_s += sp.dt
         self.stats.prefill_tokens += S * len(warm)
+        self.stats.prefill_prompt_tokens += real
         self._pools = {n: cache[n] for n in self._pool_names}
         self._tables_dirty = True
         rows = self._put([i for i, _ in warm])
@@ -1282,47 +1308,67 @@ class Engine:
     def step(self) -> List[GenRequest]:
         """One engine iteration: sample a token for every resident sequence,
         retire finished ones, prefill admissions into freed slots, then run
-        one batched decode step for the sequences that continue."""
+        one batched decode step for the sequences that continue.
+
+        The slot/paged step records one ``engine.step`` wall span, and
+        inside it a span per phase: ``engine.admit`` (the prefills nest in
+        it), ``engine.sample``, ``engine.retire``, ``engine.pages``,
+        ``engine.decode_step`` and ``engine.carry``."""
         if not self.slot_decode:
             return self._step_wave_legacy()
         if self.spec:
             return self._step_spec()
-        self._admit()
+        tr = get_tracer()
+        with tr.wall("engine.step", who=self.owner) as sp:
+            finished = self._step_phases(tr)
+        self.stats.steps += 1
+        self.stats.step_wall_s += sp.dt
+        return finished
+
+    def _step_phases(self, tr) -> List[GenRequest]:
+        who = self.owner
+        with tr.wall("engine.admit", who=who):
+            self._admit()
         resident = [i for i, s in enumerate(self._slots) if s is not None]
         if not resident:
             return []
         # 1. sample next token for all resident rows from their current logits
-        temps_np = np.zeros(self.max_batch, np.float32)
-        for i in resident:
-            temps_np[i] = self._slots[i].req.temperature
-        temps = 0.0 if (temps_np <= 0.0).all() else self._put(temps_np)
-        cur = self._sample(self._logits, temps)
-        cur_np = np.asarray(cur[:, 0])
+        with tr.wall("engine.sample", who=who, rows=len(resident)):
+            temps_np = np.zeros(self.max_batch, np.float32)
+            for i in resident:
+                temps_np[i] = self._slots[i].req.temperature
+            temps = 0.0 if (temps_np <= 0.0).all() else self._put(temps_np)
+            cur = self._sample(self._logits, temps)
+            cur_np = np.asarray(cur[:, 0])
         now = wall_now()
         finished: List[GenRequest] = []
         survivors: List[int] = []
-        for i in resident:
-            if self._append_token(i, int(cur_np[i]), now, finished):
-                survivors.append(i)
+        with tr.wall("engine.retire", who=who):
+            for i in resident:
+                if self._append_token(i, int(cur_np[i]), now, finished):
+                    survivors.append(i)
         # 2. admit queued work into freed slots between decode steps
         if self.continuous and finished:
-            self._admit()
+            with tr.wall("engine.admit", who=who):
+                self._admit()
         # 2b. paged: claim this step's write page per survivor, preempting
         #     the most recent admissions if the pool is exhausted
         if self.paged and survivors:
-            survivors = self._ensure_decode_pages(survivors)
+            with tr.wall("engine.pages", who=who):
+                survivors = self._ensure_decode_pages(survivors)
         # 3. one batched decode step advances the surviving rows; rows that
         #    were empty or just prefilled ride along (static batch shape) —
         #    their cache write lands at their own depth and is overwritten by
         #    their first real decode, and their logits are kept, not replaced
         if survivors:
-            with get_tracer().wall("engine.decode_step", who=self.owner,
-                                   batch=len(survivors)) as spn:
+            with tr.wall("engine.decode_step", who=who,
+                         rows=len(survivors)) as spn:
                 if self.paged:
                     # trim the table to the pages live rows can actually
                     # touch and reuse the device-resident copy whenever no
                     # host-side mutation invalidated it (§Perf-kernels)
                     w = self._table_width()
+                    spn.note(width=w)
                     if (self._tables_dirty or self._bt_dev is None
                             or self._bt_dev.shape[1] != w):
                         self._bt_dev = self._put(self._block_tables[:, :w])
@@ -1351,9 +1397,10 @@ class Engine:
                     self._cache = {k: v for k, v in cache.items()
                                    if k != "length"}
             self.stats.decode_wall_s += spn.dt
-            keep = self._put(survivors)
-            self._logits = self._logits.at[keep].set(logits[keep])
-            self._lengths[survivors] += 1
+            with tr.wall("engine.carry", who=who):
+                keep = self._put(survivors)
+                self._logits = self._logits.at[keep].set(logits[keep])
+                self._lengths[survivors] += 1
             self.stats.decode_tokens += len(survivors)
             self.stats.decode_steps += 1
         return finished

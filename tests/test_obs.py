@@ -4,9 +4,10 @@ Four layers:
 
 1.  ``Tracer``/``WallSpan`` unit tests — a disabled tracer is a no-op,
     ``Tracer.wall`` ALWAYS measures (the ``EngineStats`` accumulators
-    depend on ``dt`` with tracing off) but only records when enabled.
-2.  ``MetricsRegistry`` unit tests — labeled series, snapshot shape,
-    type-conflict rejection — plus the ``core.network`` event accounting
+    depend on ``dt`` with tracing off) but only records when enabled,
+    and only an enabled tracer opens the caller's profiler annotation.
+2.  ``MetricsRegistry`` unit tests — labeled series, snapshot shape —
+    plus the ``core.network`` event accounting
     (satellite of DESIGN.md §Observability): queued-request drops feed
     both ``msg_counts["dropped"]`` and the labeled registry counter.
 3.  Export tests — Chrome ``trace_event`` structure (two clock-domain
@@ -25,7 +26,7 @@ import json
 
 import pytest
 
-from repro.obs import (SIM, WALL, Histogram, MetricsRegistry, Tracer,
+from repro.obs import (SIM, WALL, MetricsRegistry, Tracer,
                        breakdown_report, get_registry, get_tracer,
                        latency_breakdown, set_registry, set_tracer,
                        to_chrome_trace, wall_now, write_chrome_trace)
@@ -74,6 +75,68 @@ class TestTracer:
             else:
                 assert tr.spans == []
 
+    def test_disabled_tracer_never_calls_the_annotation_factory(self):
+        calls = []
+        tr = Tracer(enabled=False, annotate=lambda name: calls.append(name))
+        for _ in range(3):
+            with tr.wall("engine.step") as sp:
+                with tr.wall("engine.sample"):
+                    pass
+        tr.span("route.decide", "r1", "n0", 0.0, 1.0)
+        assert calls == [] and tr.spans == [] and sp.dt > 0.0
+
+    def test_enabled_tracer_annotates_each_wall_block_around_it(self):
+        log = []
+
+        class Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("open", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("close", self.name, exc[0]))
+
+        tr = Tracer(annotate=Ann)
+        with tr.wall("engine.step"):
+            with tr.wall("engine.sample"):
+                pass
+            with tr.wall("engine.decode_step", rows=2) as sp:
+                sp.note(width=8)
+        # sim-clock spans and events are never annotated
+        tr.span("route.decide", "r1", "n0", 0.0, 1.0)
+        tr.event("executor.admit", "r1", "n0", 1.0)
+        assert log == [("open", "engine.step"), ("open", "engine.sample"),
+                       ("close", "engine.sample", None),
+                       ("open", "engine.decode_step"),
+                       ("close", "engine.decode_step", None),
+                       ("close", "engine.step", None)]
+        walls = [s for s in tr.spans if s.clock == WALL]
+        assert [s.name for s in walls] == [
+            "engine.sample", "engine.decode_step", "engine.step"]
+        assert walls[1].attrs == {"rows": 2, "width": 8}
+
+    def test_annotation_closes_when_the_block_raises(self):
+        log = []
+
+        class Ann:
+            def __init__(self, name):
+                pass
+
+            def __enter__(self):
+                log.append("open")
+
+            def __exit__(self, *exc):
+                log.append(exc[0])
+
+        tr = Tracer(annotate=Ann)
+        with pytest.raises(ValueError):
+            with tr.wall("engine.sample"):
+                raise ValueError("boom")
+        assert log == ["open", ValueError]
+        assert [s.name for s in tr.spans] == ["engine.sample"]
+
     def test_by_request_groups_sorts_and_drops_batch_spans(self):
         tr = Tracer()
         tr.span("engine.decode", "r1", "n0", 2.0, 3.0)
@@ -112,38 +175,14 @@ class TestMetricsRegistry:
         assert reg.value("net.messages", kind="gossip") == 1.0
         assert reg.value("net.messages", kind="bounce") == 0.0
 
-    def test_gauge_is_last_write_wins(self):
-        reg = MetricsRegistry()
-        reg.gauge("queue.depth", node="n0").set(4.0)
-        reg.gauge("queue.depth", node="n0").set(2.0)
-        assert reg.value("queue.depth", node="n0") == 2.0
-
-    def test_histogram_buckets_and_overflow(self):
-        reg = MetricsRegistry()
-        h = reg.histogram("latency", buckets=(0.1, 1.0, 10.0))
-        for v in (0.05, 0.5, 5.0, 50.0):
-            h.observe(v)
-        assert h.count == 4 and h.sum == pytest.approx(55.55)
-        assert h.counts == [1, 1, 1]         # 50.0 -> implicit +inf bucket
-        assert isinstance(h, Histogram)
-
     def test_snapshot_shape_and_series_keys(self):
         reg = MetricsRegistry()
         reg.counter("net.dropped", reason="offline").inc()
-        reg.gauge("g").set(1.5)
-        reg.histogram("h", buckets=(1.0,)).observe(0.5)
+        reg.counter("engine.preempted").inc(2)
         snap = reg.snapshot()
-        assert snap["counters"] == {"net.dropped{reason=offline}": 1.0}
-        assert snap["gauges"] == {"g": 1.5}
-        assert snap["histograms"]["h"] == {
-            "count": 1, "sum": 0.5, "bounds": [1.0], "counts": [1]}
+        assert snap == {"counters": {"engine.preempted": 2.0,
+                                     "net.dropped{reason=offline}": 1.0}}
         json.dumps(snap)                     # JSON-able end to end
-
-    def test_type_conflict_raises(self):
-        reg = MetricsRegistry()
-        reg.counter("x", a=1)
-        with pytest.raises(TypeError):
-            reg.gauge("x", a=1)
 
     def test_set_registry_swaps_and_restores(self):
         mine = MetricsRegistry()
