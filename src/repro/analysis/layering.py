@@ -49,7 +49,10 @@ ALLOWED_IMPORTS: Dict[str, Tuple[str, ...]] = {
     "obs": (),                            # stdlib-only trace/metrics sink
     "sim": ("compat", "obs"),
     "core": ("compat", "obs", "sim"),
-    "models": ("compat",),
+    # the paged decode step dispatches its attention through kernels.ops
+    # (imported where it is called: kernels import models' attention
+    # helpers, whose package import would otherwise come round again)
+    "models": ("compat", "kernels"),
     "kernels": ("compat", "models"),      # ref oracles live in models
     "configs": ("compat", "models"),
     "training": ("compat", "models", "data"),

@@ -43,36 +43,46 @@ def decode(q, k_cache, v_cache, cache_len, *, window: Optional[int] = None,
     return decode_ref(q, k_cache, v_cache, cache_len, window=window)
 
 
+def _at_layer(layer, *pools):
+    """The jnp oracles take one layer's (P, page, Hkv, D|1) pools."""
+    return pools if layer is None else tuple(p[layer] for p in pools)
+
+
 @functools.partial(jax.jit, static_argnames=("backend", "pages_per_step"))
-def paged_decode(q, k_pool, v_pool, block_tables, lengths, *,
+def paged_decode(q, k_pool, v_pool, block_tables, lengths, *, layer=None,
                  backend: str = "auto",
                  pages_per_step: Optional[int] = None) -> jax.Array:
-    """Block-table paged decode. q: (B,1,H,D); pools: (P,page,Hkv,D);
-    block_tables: (B,maxp) int32; lengths: (B,) int32.  ``pages_per_step``
-    overrides the recorded kernel tuning (Pallas path only)."""
+    """Block-table paged decode. q: (B,1,H,D); pools: (L,P,page,Hkv,D)
+    read at the int32 scalar ``layer``, or one layer's (P,page,Hkv,D);
+    block_tables: (B,maxp) int32; lengths: (B,) int32.  The kernel reads
+    the pool in place; ``pages_per_step`` overrides the recorded kernel
+    tuning (Pallas path only)."""
     use_pallas = backend == "pallas" or (backend == "auto" and on_tpu())
     if use_pallas:
         return flash_paged_decode_tpu(q, k_pool, v_pool, block_tables,
-                                      lengths,
+                                      lengths, layer=layer,
                                       pages_per_step=pages_per_step)
-    return paged_decode_ref(q, k_pool, v_pool, block_tables, lengths)
+    return paged_decode_ref(q, *_at_layer(layer, k_pool, v_pool),
+                            block_tables, lengths)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "pages_per_step"))
 def paged_decode_quant(q, k_pool, v_pool, k_scale, v_scale, block_tables,
-                       lengths, *, backend: str = "auto",
+                       lengths, *, layer=None, backend: str = "auto",
                        pages_per_step: Optional[int] = None) -> jax.Array:
     """Int8 block-table paged decode (DESIGN.md §6.1-paged): int8 pools
-    plus (P,page,Hkv,1) per-token-per-head scale pools riding the same
-    block-table indirection; dequantized in the kernel body."""
+    plus per-token-per-head scale pools (the pools' shape with a trailing
+    1) riding the same block-table indirection; the kernel applies the
+    scales in its body.  ``layer`` as in :func:`paged_decode`."""
     use_pallas = backend == "pallas" or (backend == "auto" and on_tpu())
     if use_pallas:
         return flash_paged_decode_tpu(q, k_pool, v_pool, block_tables,
                                       lengths, k_scale=k_scale,
-                                      v_scale=v_scale,
+                                      v_scale=v_scale, layer=layer,
                                       pages_per_step=pages_per_step)
-    return paged_decode_quant_ref(q, k_pool, v_pool, k_scale, v_scale,
-                                  block_tables, lengths)
+    return paged_decode_quant_ref(
+        q, *_at_layer(layer, k_pool, v_pool, k_scale, v_scale),
+        block_tables, lengths)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "pages_per_step"))

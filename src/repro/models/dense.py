@@ -394,8 +394,13 @@ def _block_decode_paged(lp: Dict, cfg: ModelConfig, x: jax.Array, pools: Dict,
     q, k, v = _project_qkv(lp, cfg, h, pos)
     pools = _scatter_pool_writes(pools, l, phys_page, page_slot, k, v,
                                  squeeze=True)
-    kg, vg = _gather_layer_pages(pools, l, block_tables, cfg)
-    attn = decode_attention(q, kg, vg, lengths + 1)
+    # the kernel reads layer ``l`` of the whole carried pool through the
+    # block table; off the TPU ``ops`` runs the gather oracle
+    from repro.kernels import ops
+    attend = (ops.paged_decode_quant if "k_scale_pool" in pools
+              else ops.paged_decode)
+    attn = attend(q, *(pools[n] for n in PAGED_POOL_NAMES if n in pools),
+                  block_tables, lengths + 1, layer=l)
     attn = attn.reshape(b, 1, cfg.q_dim) @ lp["wo"]
     if cfg.use_bias:
         attn = attn + lp["bo"][None, None, :]
@@ -422,7 +427,9 @@ def paged_decode_step(params: Dict, cfg: ModelConfig, cache: Dict,
     The pools ride the layer scan as **carry** (layer picked by index), not
     as sliced xs — under ``jax.jit(..., donate_argnums=...)`` the scatter
     is then a true in-place update and step cost is independent of pool
-    size (§Perf-kernels).  Returns (logits, cache with lengths+1).
+    size (§Perf-kernels).  Attention is ``repro.kernels.ops.paged_decode``
+    (``_quant`` for int8 pools): the Pallas block-table kernel on a TPU,
+    the gather oracle elsewhere.  Returns (logits, cache with lengths+1).
     """
     x = jnp.take(params["embed"], token, axis=0)
     bt = cache["block_tables"]

@@ -121,6 +121,11 @@ class EngineStats:
     step_wall_s: float = 0.0      # wall time inside them
     peak_resident: int = 0        # max concurrently resident sequences
     preempted: int = 0            # paged: preempt-and-requeue events
+    # paged decode: the pages holding each fed row's valid KV, summed over
+    # steps, and the pages of the tables those steps passed (max_batch x
+    # width): the share of the tables that the attention kernel reads
+    decode_kv_pages: int = 0
+    decode_table_pages: int = 0
     handoffs: int = 0             # disagg: KV handoffs extracted/accepted
     handoff_bytes: int = 0        # disagg: valid KV bytes handed off
     # speculative decoding (DESIGN.md §6.1-spec).  decode_tokens counts
@@ -1378,6 +1383,11 @@ class Engine:
                     logits, cache = self._decode_paged(self.params, cache,
                                                        cur)
                     logits.block_until_ready()
+                    # ceil((length + 1) / page): through the new token
+                    self.stats.decode_kv_pages += int(
+                        ((self._lengths[survivors] + self.page_size)
+                         // self.page_size).sum())
+                    self.stats.decode_table_pages += self.max_batch * w
                     self._pools = {n: cache[n] for n in self._pool_names}
                     # the cache is donated: only the RETURNED tables/lengths
                     # are valid now.  They advanced every row by one; reuse

@@ -91,6 +91,24 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
+# the served decode reads the whole (L, P, page, Hkv, D) pool at a traced
+# layer index: the benchmark's two configurations, at their widest tables
+SERVED = {  # name: (layers, pool pages, table width, query heads)
+    "qwen3-8b.l18": (18, 2800, 320, 32),
+    "qwen3-32b.l8": (8, 6800, 256, 64),
+}
+for _name, (_l, _p, _w, _h) in SERVED.items():
+    KERNELS[f"paged_decode_layer_{_name}"] = (
+        lambda q, k, v, bt, ln, l: flash_paged_decode_tpu(
+            q, k, v, bt, ln, layer=l, interpret=False),
+        [((32, 1, _h, D), BF16), ((_l, _p, PAGE, HKV, D), BF16),
+         ((_l, _p, PAGE, HKV, D), BF16), ((32, _w), jnp.int32),
+         ((32,), jnp.int32), ((), jnp.int32)])
+# what the gather path's decode step held in temporaries at the chat
+# configuration's widest table (memory_analysis() for a described v5e)
+GATHER_TEMP_BYTES = 3.44e9
+
+
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     fn, shapes = KERNELS[name]
@@ -98,3 +116,45 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["bf16", "int8"])
+def test_served_decode_step_compiles_without_pool_copies(
+        kv_quant, one_chip, no_persistent_cache, monkeypatch):
+    """The whole ``jit_paged_decode_step`` of qwen3-8b.l18 (abstract
+    weights, 2,800-page pool, 320-page table) lowers its attention to the
+    Mosaic kernel, and its temporaries hold no pool-sized copy."""
+    import repro.compat.pallascompat as pallascompat
+    from repro.kernels import ops
+    from repro.models import dense
+    from repro.models.config import ModelConfig
+
+    # this process's backend is the CPU: steer the dispatch to the kernel
+    # and its compilation for the described chip
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(pallascompat, "on_tpu", lambda: True)
+    layers, pages, width, heads = SERVED["qwen3-8b.l18"]
+    cfg = ModelConfig(name="qwen3-8b.l18", family="dense", n_layers=layers,
+                      d_model=4096, n_heads=heads, n_kv_heads=HKV,
+                      d_ff=12288, vocab_size=151936, head_dim=D,
+                      qk_norm=True, kv_quant=kv_quant)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: dense.init(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip({
+        **jax.eval_shape(lambda: dense.init_paged_pools(cfg, pages, PAGE)),
+        "block_tables": jax.ShapeDtypeStruct((32, width), jnp.int32),
+        "lengths": jax.ShapeDtypeStruct((32,), jnp.int32)})
+    token = on_chip(jax.ShapeDtypeStruct((32, 1), jnp.int32))
+
+    def paged_decode_step(p, c, t):
+        return dense.paged_decode_step(p, cfg, c, t)
+    compiled = jax.jit(paged_decode_step, donate_argnums=(1,)).lower(
+        params, cache, token).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        GATHER_TEMP_BYTES / 100)

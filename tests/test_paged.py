@@ -194,6 +194,27 @@ class TestPagedEngineParity:
             np.testing.assert_array_equal(outs[0][rid], outs[2][rid])
 
 
+class TestDecodePageCounters:
+    def test_kv_and_table_pages_count_the_kernel_grid(self, setup):
+        """One request: each decode step counts its row's pages through
+        the new token, ceil((depth + 1) / page), against max_batch rows
+        of the table's width."""
+        from repro.serving import Engine, GenRequest
+        cfg, params = setup
+        eng = Engine(cfg, params, max_batch=2, bucket=16, paged=True,
+                     page_size=4, num_pages=16)
+        plen = 9
+        eng.serve([GenRequest(rid="r", tokens=np.arange(2, 2 + plen,
+                                                        dtype=np.int32),
+                              max_new=6)])
+        st = eng.stats
+        assert st.decode_steps > 0
+        depths = range(plen, plen + st.decode_steps)
+        assert st.decode_kv_pages == sum(-(-(d + 1) // 4) for d in depths)
+        assert st.decode_table_pages % 2 == 0
+        assert st.decode_kv_pages <= st.decode_table_pages
+
+
 class TestConfiguredEos:
     """Engine.eos_id comes from ModelConfig (regression for the hard-coded
     ``eos_id = 1``): a prompt-configured EOS terminates decode early in both
