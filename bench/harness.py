@@ -6,6 +6,10 @@ harness finds everything else by those names:
 * ``bench/configs/<config>.json`` (the path in the config's entry): the
   model's published sizes (Hugging Face key names), the depth cut, dtype,
   the engine settings and the limit of the output check;
+* ``bench/arch/<model_type>.py``, by the configuration's ``model_type``:
+  the architecture's model configuration, sizes, work counts, weights and
+  float32 reference (the interface is ``bench/arch/__init__.py``'s
+  docstring);
 * ``bench/traffic/<traffic>.json``: the mix, read by ``bench.traffic``;
 * ``bench/metrics/<metric>.py``: one reader per metric, ``read(run)``
   returning a number or None (nothing to read: the metric is left out).
@@ -21,7 +25,8 @@ A run: draw the weights; warm every shape the mix can produce (prefill
 lengths, decode table widths, resident-row counts); serve the mix's warm
 segment; measure ``--seconds``; for an open loop keep serving until every
 request due in the window has its first token; read the peak memory; free
-the engine; hold a sample of the finished requests to ``bench.reference``.
+the engine; hold a sample of the finished requests to the
+architecture's float32 reference.
 """
 
 from __future__ import annotations
@@ -38,14 +43,15 @@ import tempfile
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from types import ModuleType
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
-from bench import readings, reference, stats, trace as tracemod
+from bench import readings, stats, trace as tracemod
+from bench.reference import gaps as reference_gaps
 from bench.traffic import Item, Traffic, prompt_range, seed_rng
-from bench.work import Sizes
 
 ROOT = Path(__file__).resolve().parents[1]
 REQUIRED_PLATFORM = "tpu"
@@ -69,6 +75,7 @@ class Cell:
     chips: int
     config_name: str
     config: Dict
+    arch: ModuleType               # bench/arch/<model_type>.py
     mix_name: str
     mix: Dict
     end_to_end: List[Dict]
@@ -94,12 +101,24 @@ def load_cell(root: Path, workload: str) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in names)]
+    config = _load_json(root / conf["file"])
     return Cell(root=root, name=workload, chips=int(w["chips"]),
-                config_name=w["config"], config=_load_json(root / conf["file"]),
+                config_name=w["config"], config=config,
+                arch=architecture(root, config["model_type"]),
                 mix_name=w["traffic"],
                 mix=_load_json(root / "bench" / "traffic"
                                / f"{w['traffic']}.json"),
                 end_to_end=e2e, per_layer=per_layer)
+
+
+def load_module(path: Path, prefix: str) -> ModuleType:
+    """The Python file at ``path``, loaded by path under a module name made
+    from ``prefix`` and the file's name."""
+    name = f"{prefix}_{path.stem.replace('.', '_').replace('-', '_')}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(root: Path, metric: str) -> Callable:
@@ -109,11 +128,25 @@ def reader(root: Path, metric: str) -> Callable:
     path = root / "bench" / "metrics" / f"{metric}.py"
     if not path.exists():
         path = path.with_name(f"{metric.split('.')[0]}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(path, "bench_metric").read
+
+
+def architectures(root: Path) -> List[str]:
+    """The ``model_type`` of every architecture module under ``root``:
+    the files in ``bench/arch`` are the list."""
+    return sorted(p.stem for p in (root / "bench" / "arch").glob("*.py")
+                  if p.stem != "__init__")
+
+
+def architecture(root: Path, model_type: str) -> ModuleType:
+    """``bench/arch/<model_type>.py``; an unknown ``model_type`` fails
+    with the names that are known."""
+    if model_type not in architectures(root):
+        raise ValueError(f"no architecture module for model_type "
+                         f"{model_type!r} in bench/arch; known: "
+                         f"{architectures(root)}")
+    return load_module(root / "bench" / "arch" / f"{model_type}.py",
+                       "bench_arch")
 
 
 def accelerator(chips: int):
@@ -165,7 +198,7 @@ class CompileStats:
 # ------------------------------------------------------------------ node
 @dataclass
 class Node:
-    sizes: Sizes
+    sizes: Any                    # the architecture's sizes(config)
     cfg: object
     weights: Dict
     engine: object
@@ -173,30 +206,15 @@ class Node:
     device: object
 
 
-def model_config(config: Dict):
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=config["name"], family="dense",
-        n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"],
-        vocab_size=config["vocab_size"], head_dim=config["head_dim"],
-        qk_norm=True, rope_theta=float(config["rope_theta"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        eos_id=int(config["eos_token_id"]), dtype=config["torch_dtype"])
-
-
 def build_node(cell: Cell, seed: int, device) -> Node:
-    from bench.weights import draw, seed32
+    from bench.weights import seed32
     from repro.serving import Engine, EngineExecutor
-    sizes = Sizes.of(cell.config)
-    cfg = model_config(cell.config)
+    sizes = cell.arch.sizes(cell.config)
+    cfg = cell.arch.model_config(cell.config)
     if cfg.padded_vocab != sizes.padded_vocab:
         raise ValueError(f"padded vocabulary {sizes.padded_vocab} in the "
                          f"config, {cfg.padded_vocab} in the program")
-    weights = draw(sizes, seed, device, dtype=cell.config["torch_dtype"])
+    weights = cell.arch.draw(cell.config, seed, device)
     jax.block_until_ready(weights)
     eng = cell.config["engine"]
     engine = Engine(cfg, weights, max_batch=eng["max_batch"],
@@ -251,6 +269,8 @@ class Pump:
         self.requests: List[Req] = []
         self.steps: List[Step] = []
         self.preemptions = 0
+        # host-clock (perf_counter) bounds of the bench.traced span
+        self.traced: Optional[Tuple[float, float]] = None
 
     def offer(self, item: Item, due: float) -> Req:
         from repro.serving import GenRequest
@@ -386,13 +406,16 @@ class Run:
     requests: List[Req]
     steps: List[Step]
     stats: Dict[str, float]    # EngineStats deltas over the window
-    sizes: Sizes
+    sizes: Any                 # the architecture's sizes(config)
     setup_s: float
     preemptions: int
     compiles_in_window: int
     cache_hits_in_window: int
     peaks: Optional[Dict] = None
     trace: Optional[tracemod.Reduced] = None
+    # host-clock bounds of the traced span (``--trace 1``): the steps
+    # between them are the ones the trace's device ops belong to
+    traced: Optional[Tuple[float, float]] = None
 
     @property
     def window_steps(self) -> List[Step]:
@@ -418,8 +441,14 @@ def window_shape(run: Run) -> str:
               if r.finished_at is not None and run.lo <= r.finished_at < run.hi
               and len(r.gen.result) < r.item.max_new)
     gaps = np.diff([s.t for s in steps]) * 1000.0
-    gap = (f"median {float(np.median(gaps)):.2f} ms, longest "
-           f"{float(gaps.max()):.2f} ms" if len(gaps) else "none")
+    if len(gaps):
+        med = float(np.median(gaps))
+        slow = gaps[gaps > 4.0 * med]
+        gap = (f"median {med:.2f} ms, longest {float(gaps.max()):.2f} ms, "
+               f"{len(slow)} over 4x the median totalling "
+               f"{float(slow.sum()):.1f} ms")
+    else:
+        gap = "none"
     return (f"steps by the longest row's pages, as a power of two: "
             f"{dict(sorted(widths.items()))}; longest context {longest} "
             f"tokens; {len(readings.prefill_tokens(run))} prefills; {eos} "
@@ -464,9 +493,11 @@ def serve(pump: Pump, traffic: Traffic, seconds: float,
             jax.profiler.start_trace(trace_dir)
             tracing = jax.profiler.TraceAnnotation(tracemod.WINDOW_SPAN)
             tracing.__enter__()
+            traced_lo = time.perf_counter()
         if "hi" not in snap and now >= hi:
             snap["hi"], comp["hi"] = _engine_stats(node.engine), cs.snapshot()
             if tracing is not None:
+                pump.traced = (traced_lo, time.perf_counter())
                 tracing.__exit__(None, None, None)
                 jax.profiler.stop_trace()
                 trace_at = None
@@ -540,24 +571,26 @@ def check_sample(reqs: Sequence[Req], seed: int, since: float
     return out
 
 
-def served_gaps(weights: Dict, sizes: Sizes, theta: float, reqs: List[Req],
+def served_gaps(cell: Cell, weights: Dict, reqs: List[Req],
                 control: Optional[str] = None) -> Tuple[int, float]:
     """(tokens, worst gap) of the served tokens of ``reqs`` against the
-    float32 reference.  With ``control`` ("int8", "fp8") the control's:
-    the gap of the token that the reference in that precision puts first
-    at each of those positions."""
+    architecture's float32 reference.  With ``control`` ("int8", "fp8")
+    the control's: the gap of the token that the reference in that
+    precision puts first at each of those positions."""
     tokens, worst = 0, 0.0
     for r in reqs:
         out = np.asarray(r.gen.result, np.int64)
         seq = np.concatenate([r.item.tokens, out])
         p = r.item.prompt_len
-        ref = reference.logits(weights, sizes, theta, seq, p - 1, len(out))
+        ref = cell.arch.reference_logits(weights, cell.config, seq, p - 1,
+                                         len(out))
         chosen = out
         if control:
-            low = reference.logits(weights, sizes, theta, seq, p - 1,
-                                   len(out), control=control)
+            low = cell.arch.reference_logits(weights, cell.config, seq,
+                                             p - 1, len(out),
+                                             control=control)
             chosen = low.argmax(axis=1)
-        g = reference.gaps(ref, chosen)
+        g = reference_gaps(ref, chosen)
         tokens += len(out)
         worst = max(worst, float(g.max()))
     return tokens, worst
@@ -619,7 +652,8 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
               stats=stats.deltas(snap["hi"], snap["lo"]), sizes=node.sizes,
               setup_s=setup_s, preemptions=pump.preemptions,
               compiles_in_window=comp["hi"][0] - comp["lo"][0],
-              cache_hits_in_window=comp["hi"][1] - comp["lo"][1])
+              cache_hits_in_window=comp["hi"][1] - comp["lo"][1],
+              traced=pump.traced)
     if trace_dir:
         try:
             red = tracemod.reduce(tracemod.load(
@@ -648,12 +682,11 @@ def calibrate(root: Path, workload: str, seeds: Sequence[int],
         run, node, pump = measure(cell, seed, seconds, False,
                                     time.perf_counter(), log=lambda *_: None)
         sample = check_sample(run.requests, seed, run.lo)
-        weights, sizes, theta = node.weights, node.sizes, node.cfg.rope_theta
+        weights = node.weights
         del pump, node, run
         gc.collect()
-        prog = Check(len(sample), *served_gaps(weights, sizes, theta, sample),
-                     limit)
-        ctrl = Check(len(sample), *served_gaps(weights, sizes, theta, sample,
+        prog = Check(len(sample), *served_gaps(cell, weights, sample), limit)
+        ctrl = Check(len(sample), *served_gaps(cell, weights, sample,
                                                control=control), limit)
         del weights
         gc.collect()
@@ -714,10 +747,10 @@ def main(argv=None, root: Path = ROOT, t_start: Optional[float] = None
     print(f"window held: {window_shape(run)}")
     # the output check runs once the engine and its page pool are freed
     sample = check_sample(run.requests, args.seed, run.lo)
-    weights, sizes, theta = node.weights, node.sizes, node.cfg.rope_theta
+    weights = node.weights
     del pump, node
     gc.collect()
-    tokens, worst = served_gaps(weights, sizes, theta, sample)
+    tokens, worst = served_gaps(cell, weights, sample)
     chk = Check(len(sample), tokens, worst,
                 float(cell.config["check"]["worst_gap_std"]))
     metrics: Dict[str, Dict] = {}

@@ -47,13 +47,9 @@ def prefill_tokens(run) -> List[int]:
 
 
 def decode_work(run):
-    """(flops, bytes) the window's decode steps required."""
-    flops = nbytes = 0.0
-    for s in run.window_steps:
-        if s.decoded:
-            flops += run.sizes.decode_flops(s.decoded, s.context)
-            nbytes += run.sizes.decode_bytes(s.decoded, s.context)
-    return flops, nbytes
+    """(flops, bytes) the window's decode steps required, as the cell's
+    architecture counts them (``bench/arch/<model_type>.py``)."""
+    return run.cell.arch.decode_work(run)
 
 
 def decode_share(run, of: str) -> Optional[float]:

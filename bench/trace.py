@@ -11,12 +11,16 @@ idle gaps, each labelled by what the host was doing.
 * Host spans are the benchmark's own ``jax.profiler.TraceAnnotation``
   events (names starting ``bench.``) on the host plane.  An idle gap is
   labelled by the innermost such span that covers its middle.
+* Per-op device seconds inside the window are kept for every op, keyed
+  by the op's base name (``%paged_decode.10`` reads ``paged_decode``), so
+  a kernel's reader finds its time however the compiler numbered it.
 """
 
 from __future__ import annotations
 
 import glob
 import os
+import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -49,6 +53,15 @@ def op_name(name: str) -> str:
     """An XLA op's event name up to its HLO text: ``%while.1 = (...)``
     reads ``%while.1``."""
     return name.split(" = ", 1)[0]
+
+
+_SUFFIX = re.compile(r"(\.\d+)+$")
+
+
+def base_name(op: str) -> str:
+    """An op's name without the ``%`` and the HLO number: ``%fusion.149``
+    and ``%fusion`` read ``fusion``."""
+    return _SUFFIX.sub("", op.lstrip("%"))
 
 
 def load(path: str) -> Trace:
@@ -115,6 +128,8 @@ class Reduced:
     window_s: float        # length of the traced window
     top_ops: List[Tuple[str, float]]      # (op name, seconds), summed
     idle_gaps: List[Tuple[str, float]]    # (host span label, seconds)
+    # seconds of every op in the window, summed by base_name()
+    op_seconds: Dict[str, float] = field(default_factory=dict)
 
     @property
     def idle_share(self) -> float:
@@ -144,8 +159,12 @@ def reduce(tr: Trace, top: int = 10) -> Optional[Reduced]:
     if not any(busy):
         return None
     ops_sorted = sorted(per_op.items(), key=lambda kv: -kv[1])[:top]
+    by_base: Dict[str, float] = {}
+    for n, s in per_op.items():
+        by_base[base_name(n)] = by_base.get(base_name(n), 0.0) + s / 1e9
     return Reduced(
         busy_s=sum(busy) / len(busy) / 1e9,
         window_s=(hi - lo) / 1e9,
         top_ops=[(n, s / 1e9) for n, s in ops_sorted],
-        idle_gaps=sorted(gaps, key=lambda g: -g[1])[:top])
+        idle_gaps=sorted(gaps, key=lambda g: -g[1])[:top],
+        op_seconds=by_base)
